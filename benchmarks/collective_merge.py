@@ -59,7 +59,6 @@ def _programs(mesh, nsh, b, hkv, g, d):
     from repro.core.softmax import (SoftmaxStats, stats_merge_collective,
                                     stats_merge_collective_packed)
     from repro.core.vexp import get_exp_fn
-    from repro.distributed.compression import shard_map
 
     exp_fn = get_exp_fn("vexp")
 
@@ -93,8 +92,9 @@ def _programs(mesh, nsh, b, hkv, g, d):
 
         return _chain(t[0], merge_one)
 
-    return {name: jax.jit(shard_map(fn, mesh=mesh,
-                                    in_specs=(P("model"),), out_specs=P()))
+    return {name: jax.jit(jax.shard_map(fn, mesh=mesh,
+                                        in_specs=(P("model"),),
+                                        out_specs=P(), check_vma=False))
             for name, fn in (("packed", packed_fn), ("split", split_fn))}
 
 

@@ -23,11 +23,12 @@ def _emit(section, rows):
 def _subprocess_report(module: str):
     """Benchmarks that need a multi-device host platform require XLA_FLAGS
     set *before* jax initializes — run them in a subprocess and relay
-    their rows."""
+    their rows. The child runs on CPU virtual devices only, so it can
+    never contend with this process for an accelerator."""
     import os
     import subprocess
 
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     out = subprocess.run(
         [sys.executable, "-m", f"benchmarks.{module}"],

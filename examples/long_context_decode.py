@@ -27,10 +27,8 @@ from repro.distributed import sharding as shd
 
 
 def _mesh_2x4():
-    # AxisType landed after 0.4.x; older jax meshes are implicitly "auto".
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-          if hasattr(jax.sharding, "AxisType") else {})
-    return jax.make_mesh((2, 4), ("data", "model"), **kw)
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main():

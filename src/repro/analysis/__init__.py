@@ -33,7 +33,7 @@ anything new. See ``cli.py`` for flags.
 under pytest): takes a jitted callable + args and reports collective
 count/kinds per lowered program (the PR-4 one-collective-per-layer
 budget), donation consumption (every ``donate_argnums`` buffer actually
-aliased in the lowered program), and carry stability (the decode carry
+aliased in the compiled program), and carry stability (the decode carry
 pytree keeps identical dtypes/shapes/shardings across the step — the
 exact PR-5 bug class).
 

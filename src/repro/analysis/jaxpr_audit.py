@@ -9,11 +9,11 @@ wrapper that raises a typed ``AssertionError`` subclass:
   packed sharded decode step is exactly ONE ``all_gather`` per layer,
   and unsharded programs are collective-free.
 * **donation** — every ``donate_argnums`` buffer must actually be
-  consumed (aliased to an output) by the lowered program. XLA only
+  consumed (aliased to an output) by the compiled program. XLA only
   *warns* on an unconsumed donation at execution time; a dtype drift in
   the carry silently turns donation off and doubles decode-state memory
-  (the PR-5 bf16 conv-state bug). Consumed donations show up as
-  ``tf.aliasing_output`` attributes on ``@main`` parameters.
+  (the PR-5 bf16 conv-state bug). Consumed donations show up as entries
+  of the compiled module's ``input_output_alias``.
 * **carry stability** — the decode carry pytree (state, positions) must
   come out of the step with the same treedef, dtypes, shapes (and
   shardings, when present) it went in with. Checked abstractly via
@@ -27,10 +27,10 @@ wrapper that raises a typed ``AssertionError`` subclass:
   cheap at test shapes, and the jit cache makes it free on a program
   the engine already built.
 
-All three accept either a jitted callable plus example/abstract args, an
+All accept either a jitted callable plus example/abstract args, an
 already-``.lower()``-ed object, or (for the text-based audits) the
-StableHLO text itself — keeping them cheap to aim at any program the
-engine builds.
+program text itself — StableHLO for collectives, the compiled HLO for
+donation — keeping them cheap to aim at any program the engine builds.
 """
 
 from __future__ import annotations
@@ -122,21 +122,34 @@ def assert_collective_budget(target, budget: dict, *args, **kwargs):
 
 # ---------------------------------------------------------------- donation
 
-_MAIN_SIG_RE = re.compile(r"func\.func\s+public\s+@main\((.*?)\)\s*->",
-                          re.DOTALL)
-# Two lowerings of a consumed donation: plain jit pairs the donated
-# input to its output at trace time (``tf.aliasing_output = N``);
-# shard_map programs defer the pairing to XLA and mark the param
-# ``jax.buffer_donor = true`` instead. A dropped donation (the PR-5
-# dtype drift) loses the attribute in the plain-jit case, which is
-# where the engine's unsharded programs live — the strong check.
-_ALIAS_ATTRS = ("tf.aliasing_output", "jax.buffer_donor")
+# One entry of the compiled module's ``input_output_alias`` per donated
+# parameter XLA paired with an output, e.g. ``{0}: (0, {}, may-alias)``.
+# The lowered StableHLO cannot decide this: it marks every donated
+# parameter ``jax.buffer_donor`` and leaves the pairing to the compiler,
+# which drops a donation whose aval matches no output (a buffer_donor
+# entry of the compiled module, not an alias).
+_ALIAS_RE = re.compile(r"\(\d+, \{[^}]*\}, (?:may|must)-alias\)")
+
+
+def compiled_text(target, *args, **kwargs) -> str:
+    """Compiled HLO text for ``target``: the text itself (str), a
+    ``Lowered`` or ``Compiled`` object, or a callable (jitted callables
+    are lowered directly, plain ones wrapped in ``jax.jit`` first)."""
+    if isinstance(target, str):
+        return target
+    if hasattr(target, "lower"):
+        target = target.lower(*args, **kwargs)
+    elif not hasattr(target, "as_text"):
+        target = jax.jit(target).lower(*args, **kwargs)
+    if hasattr(target, "compile"):
+        target = target.compile()
+    return target.as_text()
 
 
 @dataclass
 class DonationReport:
     donated_leaves: int            # array leaves in donated arg positions
-    aliased_params: int            # @main params carrying aliasing_output
+    aliased_params: int            # params the compiled program aliases
 
     @property
     def fully_consumed(self) -> bool:
@@ -144,22 +157,21 @@ class DonationReport:
 
 
 def donation_report(target, donate_argnums, *args, **kwargs):
-    """How many donated buffers the lowered program actually consumes.
+    """How many donated buffers the compiled program actually consumes.
 
     ``target`` must be the jitted-with-donation callable (or its
-    ``Lowered``/text); ``donate_argnums`` re-states the donated arg
-    positions so the expected leaf count can be derived from ``args``.
-    When ``target`` is pre-lowered text, pass the expected leaf count
-    directly as ``donate_argnums`` (int)."""
+    ``Lowered``/``Compiled``/compiled text); ``donate_argnums`` re-states
+    the donated arg positions so the expected leaf count can be derived
+    from ``args``. When ``target`` is already lowered or compiled, pass
+    the expected leaf count directly as ``donate_argnums`` (int)."""
     if isinstance(donate_argnums, int):
         expected = donate_argnums
     else:
         expected = 0
         for i in donate_argnums:
             expected += len(jax.tree_util.tree_leaves(args[i]))
-    text = lowered_text(target, *args, **kwargs)
-    m = _MAIN_SIG_RE.search(text)
-    aliased = sum(m.group(1).count(a) for a in _ALIAS_ATTRS) if m else 0
+    header = compiled_text(target, *args, **kwargs).split("\n", 1)[0]
+    aliased = len(_ALIAS_RE.findall(header))
     return DonationReport(donated_leaves=expected, aliased_params=aliased)
 
 
@@ -169,7 +181,7 @@ def assert_all_donated(target, donate_argnums, *args, **kwargs):
         raise DonationError(
             f"donation not consumed: {rep.donated_leaves} donated "
             f"buffer leaves but only {rep.aliased_params} aliased "
-            f"outputs in the lowered program — an unconsumed donation "
+            f"outputs in the compiled program — an unconsumed donation "
             f"silently doubles decode-state memory (the PR-5 dtype-"
             f"drift class)")
     return rep

@@ -184,6 +184,16 @@ def attention_flash(q, k, v, *, causal=True, window=None, exp_impl="vexp",
 from repro.runtime.policy import KERNEL_BACKEND_TO_ATTN_IMPL as _BACKEND_TO_IMPL  # noqa: E402,E501
 
 
+def masked_policy(policy):
+    """The policy for attention with per-row key lengths or a query offset
+    (ragged, chunked and prefix-hit prefill): ``policy`` itself, except
+    that a pallas policy runs on the reference flash scan, because the
+    Pallas flash kernel masks keys from position 0 only."""
+    if policy is None or policy.kernel_backend != "pallas":
+        return policy
+    return policy.replace(kernel_backend="reference", accum_dtype="float32")
+
+
 def attention(q, k, v, *, causal=True, window=None, exp_impl="vexp",
               q_offset=0, sm_scale=None, impl="flash", block_k=512,
               unroll=False, mm_dtype="f32", kv_valid=None, policy=None):
@@ -191,22 +201,23 @@ def attention(q, k, v, *, causal=True, window=None, exp_impl="vexp",
 
     A ``runtime.ExecPolicy`` (if given) decides impl, exp backend and block
     sizes in one object; the explicit keyword arguments remain for direct
-    use and for q_offset paths the Pallas kernel does not cover.
+    use.
 
     ``kv_valid``: optional (B, Sk) boolean key-validity mask for ragged
     (padded) prompt batches — masked key positions are excluded from both
-    attention weights and the softmax normalizer.
+    attention weights and the softmax normalizer. The Pallas kernel takes
+    neither ``kv_valid`` nor a ``q_offset``: callers that need them pick
+    their implementation through ``masked_policy``.
     """
     if policy is not None:
         impl = _BACKEND_TO_IMPL[policy.kernel_backend]
         exp_impl = policy.exp_backend
         block_k = policy.block_k
-    # The Pallas kernel has no q_offset or per-row key-mask support (its
-    # masks index from position 0); those paths take the reference flash
-    # scan or the masking would be silently wrong.
     if impl == "pallas" and (kv_valid is not None or
                              not (isinstance(q_offset, int) and q_offset == 0)):
-        impl = "flash"
+        raise ValueError(
+            "the Pallas flash kernel takes no per-row key lengths or query "
+            "offset; run masked attention under masked_policy(policy)")
     if impl == "xla":
         return attention_xla(q, k, v, causal=causal, window=window,
                              exp_impl=exp_impl, q_offset=q_offset,

@@ -11,28 +11,9 @@ expressed by casting grads before the pjit boundary (see train loop's
 
 from __future__ import annotations
 
-import functools
-
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                      # jax >= 0.6 re-exports at top level
-    from jax import shard_map as _shard_map
-except ImportError:                       # 0.4.x experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# Replication checking was renamed check_rep -> check_vma across versions;
-# pass whichever keyword this jax accepts.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
 
 
 def ef_compress(grad, err):
@@ -54,9 +35,9 @@ def compressed_psum(grads, errs, mesh: Mesh, axis: str = "data"):
             s = jax.lax.psum(c.astype(jnp.float32), axis)
             return s / mesh.shape[axis], ne
 
-        return shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P()), out_specs=(P(), P()))(g, e)
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+            check_vma=False)(g, e)
 
     out = jax.tree.map(one, grads, errs)
     means = jax.tree.map(lambda t: t[0], out,

@@ -81,20 +81,25 @@ def _decode_kernel(len_ref, off_ref, q_ref, k_ref, v_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # (block_b,) per-row lengths of this row block (scalar SMEM reads).
-    lens = jnp.stack([len_ref[bi * block_b + i] for i in range(block_b)])
+    # per-row lengths of this row block, kept as scalars: Mosaic cannot
+    # reshape a stacked (block_b,) vector into a (block_b, 1, 1) column.
+    lens = [len_ref[bi * block_b + i] for i in range(block_b)]
     seq_off = off_ref[0]
     start = si * block_s                 # shard-local block start
     g_start = start + seq_off            # absolute cache position
     exp_fn = get_exp_fn(exp_impl)
 
     # Block-level liveness: any (row, key) pair inside [len - window, len)?
-    row_live = g_start < lens
-    if window is not None:
-        # first in-window position; blocks fully below it are skipped, so
-        # the sweep effectively starts at max(0, cache_len - window)'s block.
-        row_live &= (g_start + block_s) > (lens - window)
-    live = jnp.any(row_live)
+    def row_live(ln):
+        live = g_start < ln
+        if window is not None:
+            # first in-window position; blocks fully below it are skipped,
+            # so the sweep effectively starts at max(0, len - window)'s
+            # block.
+            live &= (g_start + block_s) > (ln - window)
+        return live
+
+    live = functools.reduce(jnp.logical_or, map(row_live, lens))
 
     @pl.when(live)
     def _compute():
@@ -102,15 +107,18 @@ def _decode_kernel(len_ref, off_ref, q_ref, k_ref, v_ref, *refs,
         if layout == "bhsd":
             k = k_ref[:, 0]                                # (bb, bs, d)
             v = v_ref[:, 0]
-        else:                                              # "bshd"
-            k = k_ref[:, :, 0, :]
-            v = v_ref[:, :, 0, :]
+        else:                        # "bshd", heads folded into the lanes
+            k = k_ref[...]
+            v = v_ref[...]
         s = jax.lax.dot_general(
             q.astype(k.dtype), k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)            # (bb, G, bs)
         lpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         kpos = lpos + seq_off
-        lcol = lens[:, None, None]
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        lcol = jnp.full(s.shape, lens[0], jnp.int32)
+        for i in range(1, block_b):
+            lcol = jnp.where(row == i, lens[i], lcol)
         keep = kpos < lcol
         # shard-local padding rows (lpos >= s_valid) may sit at absolute
         # positions that *are* valid on later shards — mask them explicitly.
@@ -168,18 +176,30 @@ def resolve_block_b(b: int, block_s: int, d: int) -> int:
 
 
 def _specs(layout: str, block_b: int, g: int, bs: int, d: int):
-    """(smem, q, k/v) BlockSpecs for the given layout; grid (nB, Hkv, nS)."""
+    """(smem, q, k/v) BlockSpecs for the given layout; grid (nB, Hkv, nS).
+    "bshd" caches arrive as (B, S, Hkv * d) (see ``_kv_operand``)."""
     from jax.experimental.pallas import tpu as pltpu
     q_spec = pl.BlockSpec((block_b, 1, g, d),
                           lambda bb, hh, si: (bb, hh, 0, 0))
     if layout == "bhsd":
         kv_spec = pl.BlockSpec((block_b, 1, bs, d),
                                lambda bb, hh, si: (bb, hh, si, 0))
-    else:                                  # "bshd": (B, S, Hkv, d)
-        kv_spec = pl.BlockSpec((block_b, bs, 1, d),
-                               lambda bb, hh, si: (bb, si, hh, 0))
+    else:
+        kv_spec = pl.BlockSpec((block_b, bs, d),
+                               lambda bb, hh, si: (bb, si, hh))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return smem, q_spec, kv_spec
+
+
+def _kv_operand(layout: str, cache):
+    """The cache as the kernel reads it. A (B, S, Hkv, d) "bshd" cache is
+    viewed as (B, S, Hkv * d), so one head's block is a lane-aligned
+    (bs, d) tile: a (bs, 1, d) block of the 4-D array would break the
+    (8, 128) tiling rule on its (Hkv, d) minor dims."""
+    if layout == "bhsd":
+        return cache
+    b, s, hkv, d = cache.shape
+    return cache.reshape(b, s, hkv * d)
 
 
 def _scratch(block_b: int, g: int, d: int, accum_dtype: str):
@@ -226,7 +246,8 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, seq_offset, *,
                                lambda bb_, hh, si: (bb_, hh, 0, 0)),
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
+    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
+      _kv_operand(layout, v_cache))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -271,7 +292,8 @@ def decode_attention_kernel_partial(q, k_cache, v_cache, cache_len,
                                 lambda bb_, hh, si: (bb_, hh, 0, 0))],
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
+    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
+      _kv_operand(layout, v_cache))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -311,7 +333,8 @@ def decode_attention_kernel_packed(q, k_cache, v_cache, cache_len,
                                lambda bb_, hh, si: (bb_, hh, 0, 0)),
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
+    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
+      _kv_operand(layout, v_cache))
 
 
 def decode_attention_bhsd(q, k_cache, v_cache, cache_len, *, sm_scale: float,

@@ -216,7 +216,6 @@ def _sharded_program(mesh, seq_axis, window, sm_scale, layout: str,
     """One jitted shard_map program per (mesh, axis, window, scale, layout,
     policy) — eager shard_map would retrace the whole merge every call."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compression import shard_map
 
     s_ax = _seq_axis(layout)
     kv_spec = [None] * 4
@@ -230,10 +229,10 @@ def _sharded_program(mesh, seq_axis, window, sm_scale, layout: str,
             q, k, v, cl, off, seq_axis=seq_axis, window=window,
             sm_scale=sm_scale, layout=layout, policy=policy)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(), kv_spec, kv_spec, P()),
-        out_specs=P()))
+        out_specs=P(), check_vma=False))
 
 
 def decode_attention_sharded(q, k_cache, v_cache, cache_len, *, mesh,

@@ -394,15 +394,19 @@ def autotune_policy(op: str, policy: ExecPolicy, run: Callable[[ExecPolicy], obj
     if any(isinstance(a, jax.core.Tracer) for a in arrays):
         return base
     _STATS["misses"] += 1
-    best_overrides, best_t = {}, math.inf
+    best_overrides, best_t, error = {}, math.inf, None
     for overrides in CANDIDATES.get(op, [{}]):
         cand = base.replace(**overrides)
         try:
             t = _time_call(lambda: run(cand))
-        except Exception:
-            continue        # candidate invalid for this shape; skip
+        except Exception as e:
+            error = e       # candidate invalid for this shape; skip
+            continue
         if t < best_t:
             best_t, best_overrides = t, overrides
+    if best_t == math.inf:
+        raise RuntimeError(
+            f"autotune {op}: every candidate failed") from error
     _AUTOTUNE_CACHE[key] = best_overrides
     save_autotune_cache()
     return base.replace(**best_overrides)

@@ -6,18 +6,12 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n):
-    # AxisType landed after 0.4.x; older jax meshes are implicitly "auto".
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod (TPU v5e), 2 pods when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(shape)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -26,4 +20,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = max(1, min(model, n // data))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

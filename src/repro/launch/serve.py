@@ -99,13 +99,15 @@ import jax.numpy as jnp
 
 from repro.analysis.registry import hot_path
 from repro.configs import get_config
+from repro.core.attention import masked_policy
 from repro.ft import (FAULT_SEED_ENV, FaultInjector, InjectedFault,
                       default_chaos_rates)
 from repro.models import api
 from repro.models.block_pool import OutOfBlocks
 from repro.models.decode_state import (decode_state_for, _len_bucket,  # noqa: F401  (re-export)
                                        SPEC_PAD)
-from repro.runtime import ExecPolicy, resolve_policy, parse_policy_groups
+from repro.runtime import (ExecPolicy, resolve_policy, parse_policy_groups,
+                           use_compile_cache)
 from .mesh import make_host_mesh
 
 # Bounded admission retry: with work in flight a rejected admission just
@@ -543,8 +545,8 @@ class _Group:
         uniform = (full and all(len(r.prompt) == sp for _, r in take)
                    and self.policy.kernel_backend != "pallas")
         # uniform exact-bucket wave: no padding exists, skip the mask.
-        # (Not under a pallas policy: the ragged path demotes pallas
-        # flash-attention to the reference scan, so the fast path would
+        # (Not under a pallas policy: the ragged path runs under
+        # masked_policy, on the reference scan, so the fast path would
         # prefill through a different implementation than solo serving
         # and could flip a near-tie greedy argmax.)
         t0 = time.perf_counter()
@@ -742,11 +744,11 @@ class _Group:
                     self.injector.fire("decode.step_error"):
                 raise InjectedFault("decode dispatch failed")
             nxt = self.state.step(self.last, self.live_dev)
-        except Exception:
-            # A raised decode dispatch consumed the donated carry (real
-            # async XLA failures usually surface at the finish-time sync
-            # instead; the injected fault exercises the same recovery):
-            # rebuild the pool and re-queue the victims.
+        except InjectedFault:
+            # The chaos harness's failed dispatch: the donated carry must
+            # be presumed consumed, so rebuild the pool and re-queue the
+            # victims. Any other error is a real fault of the program or
+            # the device and propagates to the caller.
             self.step_faults += 1
             self._recover_step_fault()
             return
@@ -793,7 +795,7 @@ class _Group:
             toks = jnp.concatenate(cand, axis=1)        # (B, W)
             block, nlast, self.rem_dev = self.state.verify_step(
                 toks, snap, self.rem_dev, self.live_dev)
-        except Exception:
+        except InjectedFault:
             # same recovery contract as the plain step: the donated
             # carry (and the snapshot fed to verify) must be presumed
             # consumed; rebuild the pool and re-queue the victims.
@@ -1169,6 +1171,11 @@ class Server:
                 "p50_ttft_s": ttft[len(ttft) // 2] if ttft else 0.0,
                 "p95_ttft_s": pct(ttft, 95),
                 "policy": g.policy.describe(),
+                # serving prefill masks every wave (ragged prompts, chunk
+                # cursors, prefix history): its attention backend is
+                # whatever masked_policy makes of the group's policy
+                "prefill_attention": masked_policy(g.policy).kernel_backend,
+                "decode_attention": g.policy.kernel_backend,
                 "kv_axis": g.kv_axis,
                 # ---- lifecycle / fault counters ----
                 "cancelled": g.cancelled,
@@ -1246,7 +1253,7 @@ class Server:
                         f"group {name}: {used} pages leaked")
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2-small")
     ap.add_argument("--reduced", action="store_true")
@@ -1350,7 +1357,8 @@ def main():
     ap.add_argument("--mesh-model", type=int, default=None,
                     help="model-axis size of the serving mesh (default: "
                          "all devices when --kv-mode seq, else 1)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"[serve] compile cache: {use_compile_cache()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -1426,6 +1434,8 @@ def main():
     print(f"served {ok}/{len(out)} requests, {ntok} tokens in {dt:.2f}s "
           f"({ntok / dt:.1f} tok/s)")
     for name, s in server.stats().items():
+        print(f"  group {name}: prefill attention {s['prefill_attention']}, "
+              f"decode attention {s['decode_attention']}")
         print(f"  group {name}: {s['decode_steps']} decode steps, "
               f"request latency p50 {s['p50_req_s'] * 1e3:.1f}ms "
               f"p95 {s['p95_req_s'] * 1e3:.1f}ms, "
@@ -1472,6 +1482,15 @@ def main():
     for r in out[:3]:
         print(f"  req {r.rid} [{r.group}] len={len(r.prompt)}: "
               f"{r.out[:8]}... ({r.finish_reason})")
+    # Chaos, cancellation and deadlines drop requests on purpose; without
+    # them an unfinished request is a failure of the run.
+    if ok < len(out) and not (args.chaos or args.cancel_frac > 0
+                              or args.deadline is not None):
+        reasons = sorted({str(r.finish_reason) for r in out
+                          if r.finish_reason not in ("max_new",
+                                                     "length_cap")})
+        raise SystemExit(f"[serve] {len(out) - ok} of {len(out)} requests "
+                         f"did not finish ({', '.join(reasons)})")
 
 
 if __name__ == "__main__":

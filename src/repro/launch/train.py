@@ -180,10 +180,11 @@ def main():
                     choices=["pallas", "reference", "xla"],
                     help="kernel backend (default: config/env)")
     args = ap.parse_args()
+    from repro.runtime import resolve_policy, use_compile_cache
+    print(f"[train] compile cache: {use_compile_cache()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    from repro.runtime import resolve_policy
     policy = resolve_policy(cfg, exp_backend=args.exp_backend,
                             kernel_backend=args.kernel_backend)
     print(f"[train] policy: {policy.describe()}")

@@ -140,7 +140,6 @@ def _programs(cfg, policy, mesh=None, kv_axis=None, decode_policy=None):
             # policy's merge strategy ("packed": one collective per
             # layer).
             from jax.sharding import PartitionSpec as P
-            from repro.distributed.compression import shard_map
             from repro.distributed.sharding import serve_cache_sharding
             from .transformer import decode_step_sharded
             # one source of truth for the pool placement: the program's
@@ -158,9 +157,9 @@ def _programs(cfg, policy, mesh=None, kv_axis=None, decode_policy=None):
                 return _guard_tokens(logits, t), c, pos + live
 
             decode = jax.jit(
-                shard_map(decode_local, mesh=mesh,
-                          in_specs=(P(), P(), cspec, P(), P()),
-                          out_specs=(P(), cspec, P())),
+                jax.shard_map(decode_local, mesh=mesh,
+                              in_specs=(P(), P(), cspec, P(), P()),
+                              out_specs=(P(), cspec, P()), check_vma=False),
                 donate_argnums=(2, 3))
             # Sharded chunk prefill: plain GSPMD with the carry pinned to
             # the pool placement on BOTH sides, so prefill compute lands
@@ -1038,7 +1037,6 @@ def _paged_programs(cfg, policy, page, mesh=None, kv_axis=None,
             chunk = jax.jit(chunk_fn, donate_argnums=pool_d)
         else:
             from jax.sharding import PartitionSpec as P
-            from repro.distributed.compression import shard_map
             from .transformer import decode_step_paged_sharded
             cspec = {"k": P(None, kv_axis), "v": P(None, kv_axis)}
             tspec = P(None, kv_axis)
@@ -1050,9 +1048,9 @@ def _paged_programs(cfg, policy, page, mesh=None, kv_axis=None,
                 return _guard_tokens(logits, t), c, pos + live
 
             decode = jax.jit(
-                shard_map(decode_local, mesh=mesh,
-                          in_specs=(P(), P(), cspec, tspec, P(), P()),
-                          out_specs=(P(), cspec, P())),
+                jax.shard_map(decode_local, mesh=mesh,
+                              in_specs=(P(), P(), cspec, tspec, P(), P()),
+                              out_specs=(P(), cspec, P()), check_vma=False),
                 donate_argnums=pool_d + (4,))
             chunk = None
 

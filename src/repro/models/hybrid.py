@@ -380,7 +380,7 @@ def _attn_chunk(h, p, cfg, ck, cv, off, clens, policy=None):
     never wrap the ring — prompts fit the window (the same invariant the
     monolithic ragged path enforces) — so cache slot == absolute
     position throughout prefill. Returns (attn_out, ck, cv)."""
-    from repro.core.attention import attention
+    from repro.core.attention import attention, masked_policy
     b, c, _ = h.shape
     s = ck.shape[1]
     pos = off[:, None] + jnp.arange(c)[None, :]            # (B, C)
@@ -396,7 +396,7 @@ def _attn_chunk(h, p, cfg, ck, cv, off, clens, policy=None):
                   q_offset=off, exp_impl=cfg.exp_impl,
                   impl=cfg.attention_impl, unroll=cfg.unroll_scans,
                   block_k=cfg.attn_block_k, mm_dtype=cfg.attn_mm_dtype,
-                  kv_valid=kv_valid, policy=policy)
+                  kv_valid=kv_valid, policy=masked_policy(policy))
     return o.reshape(b, c, -1) @ p["wo"], ck, cv
 
 
@@ -503,7 +503,7 @@ def prefill_chunk_paged(params, cfg, tokens, cache, tables, off, clens, *,
     Prefill positions never wrap the ring (prompts fit the window), so
     ``tables[b, pos // page]`` is cursor-monotonic during prefill."""
     from repro.kernels.decode_attention.ops import paged_gather
-    from repro.core.attention import attention
+    from repro.core.attention import attention, masked_policy
     b, c = tokens.shape
     off = jnp.asarray(off, jnp.int32).reshape(-1)
     clens = jnp.asarray(clens, jnp.int32).reshape(-1)
@@ -529,7 +529,7 @@ def prefill_chunk_paged(params, cfg, tokens, cache, tables, off, clens, *,
                       q_offset=off, exp_impl=cfg.exp_impl,
                       impl=cfg.attention_impl, unroll=cfg.unroll_scans,
                       block_k=cfg.attn_block_k, mm_dtype=cfg.attn_mm_dtype,
-                      kv_valid=kv_valid, policy=policy)
+                      kv_valid=kv_valid, policy=masked_policy(policy))
         return o.reshape(h.shape[0], c, -1) @ ap["wo"], {"k": pk, "v": pv}
 
     return _prefill_chunk_impl(params, cfg, tokens, cache, off, clens,

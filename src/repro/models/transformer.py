@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.registry import hot_path
-from repro.core.attention import attention, decode_attention
+from repro.core.attention import attention, decode_attention, masked_policy
 from .layers import (dense_init, embed_init, norm_init, norm_apply,
                      apply_rope, mlp_init, mlp_apply, cross_entropy,
                      mask_padded_logits)
@@ -73,6 +73,8 @@ def attn_apply(x, p, cfg, pos, *, window=None, causal=None, kv_valid=None,
     """
     causal = cfg.causal if causal is None else causal
     q, k, v = _qkv(x, p, cfg, pos)
+    if kv_valid is not None:
+        policy = masked_policy(policy)
     o = attention(q, k, v, causal=causal, window=window,
                   exp_impl=cfg.exp_impl, impl=cfg.attention_impl,
                   unroll=cfg.unroll_scans, block_k=cfg.attn_block_k,
@@ -221,8 +223,8 @@ def _attn_apply_hist(x, p, cfg, pos, hk, hv, *, suffix_valid=None,
     prefix's already-roped KV gathered from the pool — always "bshd"
     regardless of ``cfg.kv_cache_layout``. Returns y and the *suffix-only*
     (k, v) (the prefix pages already exist; only the suffix is scattered
-    back). The ``q_offset``/``kv_valid`` path demotes pallas to the flash
-    scan inside ``attention`` — prefix-hot prefill is rare and short."""
+    back). The ``q_offset``/``kv_valid`` masks run under
+    ``masked_policy`` — prefix-hot prefill is rare and short."""
     b, s, _ = x.shape
     h = hk.shape[1]
     q, k, v = _qkv(x, p, cfg, pos)
@@ -236,7 +238,7 @@ def _attn_apply_hist(x, p, cfg, pos, hk, hv, *, suffix_valid=None,
                   exp_impl=cfg.exp_impl, impl=cfg.attention_impl,
                   unroll=cfg.unroll_scans, block_k=cfg.attn_block_k,
                   mm_dtype=cfg.attn_mm_dtype, kv_valid=kv_valid,
-                  policy=policy)
+                  policy=masked_policy(policy))
     return o.reshape(b, s, -1) @ p["wo"], (k, v)
 
 
@@ -546,7 +548,7 @@ def _attn_chunk(x, p, cfg, ck, cv, off, clens, *, policy=None):
                   q_offset=off, exp_impl=cfg.exp_impl,
                   impl=cfg.attention_impl, unroll=cfg.unroll_scans,
                   block_k=cfg.attn_block_k, mm_dtype=cfg.attn_mm_dtype,
-                  kv_valid=kv_valid, policy=policy)
+                  kv_valid=kv_valid, policy=masked_policy(policy))
     return o.reshape(b, c, -1) @ p["wo"], (ck, cv)
 
 
@@ -994,7 +996,7 @@ def prefill_chunk_paged(params, cfg, tokens, cache, tables, off, clens, *,
                       exp_impl=cfg.exp_impl, impl=cfg.attention_impl,
                       unroll=cfg.unroll_scans, block_k=cfg.attn_block_k,
                       mm_dtype=cfg.attn_mm_dtype, kv_valid=kv_valid,
-                      policy=policy)
+                      policy=masked_policy(policy))
         a = o.reshape(b, c, -1) @ layer_p["attn"]["wo"]
         x = _finish_block(x, h, a, layer_p, cfg, policy=policy)
         return x, {"k": pk, "v": pv}

@@ -12,7 +12,9 @@ kernels, models, serving and training.
 from .policy import (ExecPolicy, resolve_policy, policy_from_env,
                      parse_policy_groups,
                      EXP_BACKENDS, KERNEL_BACKENDS, ENV_PREFIX)
+from .compile_cache import use_compile_cache
 
 __all__ = ["ExecPolicy", "resolve_policy", "policy_from_env",
            "parse_policy_groups",
-           "EXP_BACKENDS", "KERNEL_BACKENDS", "ENV_PREFIX"]
+           "EXP_BACKENDS", "KERNEL_BACKENDS", "ENV_PREFIX",
+           "use_compile_cache"]

@@ -7,7 +7,6 @@ collective ops, so the audit counts them without multi-device state.
 """
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -17,13 +16,13 @@ def build_two_collective_step(mesh, axis="x"):
         m = jax.lax.pmax(x, axis)     # collective 2
         return s + m
 
-    return jax.jit(shard_map(step, mesh=mesh,
-                             in_specs=P(axis), out_specs=P()))
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=P(axis), out_specs=P()))
 
 
 def build_one_collective_step(mesh, axis="x"):
     def step(x):
         return jax.lax.psum(x, axis)  # exactly one collective
 
-    return jax.jit(shard_map(step, mesh=mesh,
-                             in_specs=P(axis), out_specs=P()))
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=P(axis), out_specs=P()))

@@ -73,9 +73,8 @@ class TestFsdpMultiPod:
         res = _run_sub("""
         import json
         cfg = get_config("grok-1-314b")
-        kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 3}
-              if hasattr(jax.sharding, "AxisType") else {})
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **kw)
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         specs = shd.param_specs(cfg, mesh, fsdp=True)
         flat = jax.tree.leaves(
             specs, is_leaf=lambda x: isinstance(x, P))
@@ -105,9 +104,8 @@ class TestFsdpMultiPod:
                                   cfg.vocab)
         batch = {"tokens": toks, "labels": toks}
         loss1 = jax.jit(lambda p, b: api.loss_fn(p, cfg, b))(params, batch)
-        kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 3}
-              if hasattr(jax.sharding, "AxisType") else {})
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **kw)
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         specs = shd.param_specs(cfg, mesh, fsdp=True)
         with mesh:
             pp = jax.device_put(params, shd.named(mesh, specs))
@@ -144,9 +142,8 @@ from repro.distributed import sharding as shd
 from repro import optim
 
 def mesh2x4():
-    kw = ({{"axis_types": (jax.sharding.AxisType.Auto,) * 2}}
-          if hasattr(jax.sharding, "AxisType") else {{}})
-    return jax.make_mesh((2, 4), ("data", "model"), **kw)
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 """
 
 

@@ -89,11 +89,12 @@ class TestEngineDecodePrograms:
         pol = resolve_policy(cfg, env={}, exp_backend=exp)
         _, _, decode, _ = _programs(cfg, pol)
         args = _decode_args(arch)
-        txt = decode.lower(*args).as_text()
+        lowered = decode.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})           # zero collectives
         n_carry = len(jax.tree_util.tree_leaves(args[2])) + 1
-        ja.assert_all_donated(txt, n_carry)            # cache + positions
+        ja.assert_all_donated(lowered, n_carry)        # cache + positions
         ja.assert_carry_stable(decode, args, {2: 1, 3: 2})
 
     @pytest.mark.parametrize("exp", EXP_BACKENDS)
@@ -113,13 +114,14 @@ class TestEngineDecodePrograms:
 
         pol = resolve_policy(cfg, env={}, exp_backend=exp)
         _, decode, _ = _paged_programs(cfg, pol, page)
-        txt = decode.lower(*args).as_text()
+        lowered = decode.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
         pool_leaves = len(jax.tree_util.tree_leaves(pool))
         donated = (1 if jax.default_backend() == "cpu"
                    else pool_leaves + 1)
-        ja.assert_all_donated(txt, donated)
+        ja.assert_all_donated(lowered, donated)
         # carry stability is unconditional — pool AND positions
         ja.assert_carry_stable(decode, args, {2: 1, 4: 2})
 
@@ -140,10 +142,12 @@ class TestEngineDecodePrograms:
         cache = api.init_cache(cfg, b, s)
         args = (_params(arch), jnp.zeros((b, c), jnp.int32), cache,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32))
-        txt = chunk.lower(*args).as_text()
+        lowered = chunk.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
-        ja.assert_all_donated(txt, len(jax.tree_util.tree_leaves(cache)))
+        ja.assert_all_donated(lowered,
+                              len(jax.tree_util.tree_leaves(cache)))
         ja.assert_carry_stable(chunk, args, {2: 1})
 
     @pytest.mark.parametrize("family", ("kv", "hybrid"))
@@ -163,12 +167,13 @@ class TestEngineDecodePrograms:
         _, _, chunk = _paged_programs(cfg, pol, page)
         args = (_params(arch), jnp.zeros((b, 8), jnp.int32), pool, tab,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32))
-        txt = chunk.lower(*args).as_text()
+        lowered = chunk.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
         donated = (0 if jax.default_backend() == "cpu"
                    else len(jax.tree_util.tree_leaves(pool)))
-        ja.assert_all_donated(txt, donated)
+        ja.assert_all_donated(lowered, donated)
         ja.assert_carry_stable(chunk, args, {2: 1})
 
     W = 4                               # spec_k = 3 draft lanes + bonus
@@ -195,11 +200,12 @@ class TestEngineDecodePrograms:
         pol = resolve_policy(cfg, env={}, exp_backend=exp)
         verify = _spec_programs(cfg, pol, self.W, "kv", 64, impl=impl)
         args = self._spec_args(arch)
-        txt = verify.lower(*args).as_text()
+        lowered = verify.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
         n = len(jax.tree_util.tree_leaves(args[2])) + 2
-        ja.assert_all_donated(txt, n)           # cache + pos + rem
+        ja.assert_all_donated(lowered, n)       # cache + pos + rem
         # verify returns (block, nlast, cache, pos, rem)
         ja.assert_carry_stable(verify, args, {2: 2, 3: 3, 4: 4})
 
@@ -216,10 +222,11 @@ class TestEngineDecodePrograms:
         cap = None if family == "recurrent" else 64
         verify = _spec_programs(cfg, pol, self.W, "recurrent", cap)
         args = self._spec_args(arch)
-        txt = verify.lower(*args).as_text()
+        lowered = verify.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
-        ja.assert_all_donated(txt, 2)           # pos + rem only
+        ja.assert_all_donated(lowered, 2)       # pos + rem only
         ja.assert_carry_stable(verify, args, {2: 2, 3: 3, 4: 4})
 
     @pytest.mark.parametrize("impl", ["scan", "chunk"])
@@ -241,12 +248,13 @@ class TestEngineDecodePrograms:
         args = (_params(arch), jnp.zeros((b, self.W), jnp.int32), pool,
                 tab, jnp.ones((b,), jnp.int32),
                 jnp.full((b,), 8, jnp.int32), jnp.ones((b,), jnp.int32))
-        txt = verify.lower(*args).as_text()
+        lowered = verify.lower(*args)
+        txt = lowered.as_text()
 
         ja.assert_collective_budget(txt, {})
         donated = (2 if jax.default_backend() == "cpu"
                    else len(jax.tree_util.tree_leaves(pool)) + 2)
-        ja.assert_all_donated(txt, donated)
+        ja.assert_all_donated(lowered, donated)
         ja.assert_carry_stable(verify, args, {2: 2, 4: 3, 5: 4})
 
     def test_paged_hybrid_decode_program(self):
@@ -304,11 +312,12 @@ def test_sharded_decode_one_collective_per_layer_and_donation():
         g.admit()
         st = g.state
         args = (st.params_decode, g.last, st.data, st.pos_dev, g.live_dev)
-        txt = st._decode.lower(*args).as_text()
+        lowered = st._decode.lower(*args)
+        txt = lowered.as_text()
         counts = ja.collective_counts(txt)
         ja.assert_collective_budget(txt, {{"all_gather": 1}})
         rep = ja.donation_report(
-            txt, len(jax.tree_util.tree_leaves(st.data)) + 1)
+            lowered, len(jax.tree_util.tree_leaves(st.data)) + 1)
         stable = ja.carry_report(st._decode, args, {{2: 1, 3: 2}})
         print(json.dumps({{"counts": counts,
                            "donated": rep.fully_consumed,

@@ -254,6 +254,51 @@ class TestAutotune:
             np.asarray(sm(x, policy=base)), atol=1e-6)
 
 
+    def test_all_candidates_failing_raises(self, tmp_path, monkeypatch):
+        """A tuner whose every candidate raises must raise too, and must
+        neither memoize nor persist an empty winner."""
+        path = str(tmp_path / "autotune.json")
+        monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
+        kd.autotune_cache_clear()
+        pol = ExecPolicy(kernel_backend="pallas", autotune=True)
+
+        def broken(p):
+            raise ValueError(f"block_s={p.block_s} does not lower")
+
+        x = jnp.zeros((2, 1, 4, 64), jnp.float32)
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            kd.autotune_policy("decode_attention", pol, broken, x)
+        assert kd.autotune_cache_stats()["entries"] == 0
+        assert not os.path.exists(path)
+
+
+class TestCompileCache:
+    """JAX's persistent compilation cache: placeable from outside through
+    JAX_COMPILATION_CACHE_DIR, otherwise one fixed directory inside the
+    checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_honours_env(self, tmp_path, monkeypatch):
+        from repro.runtime import use_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_inside_checkout(self, monkeypatch):
+        from repro.runtime import use_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first, second = use_compile_cache(), use_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == second == os.path.join(root, ".jax_compile_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+
+
 class TestAutotunePersistence:
     """The block-size cache persists to disk keyed by (device_kind, op,
     shape_bucket, policy): a fresh process (simulated by clearing the

@@ -327,6 +327,40 @@ class TestDonatedDecodeStep:
         srv.drain()
 
 
+class TestNoSilentFailure:
+    def test_real_decode_error_escapes(self, cfg, params, monkeypatch):
+        """Only the chaos harness's InjectedFault is recovered in the
+        decode step; any other error is a real fault and must reach the
+        caller instead of being retried and shed."""
+        srv = Server(cfg, params, max_batch=2, max_seq=64)
+        srv.submit(Request(0, _prompts(cfg, (5,))[0], 4))
+        g = srv._groups["default"]
+        g.admit()
+
+        def broken(*a, **k):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(g.state, "step", broken)
+        with pytest.raises(RuntimeError, match="device lost"):
+            g.decode_once()
+        assert g.step_faults == 0 and g.shed == 0
+
+    def test_main_exits_nonzero_when_requests_fail(self, tmp_path,
+                                                   monkeypatch, capsys):
+        """Prompts of three pages into a pool of one allocatable page can
+        never be admitted: they are shed as "failed", and without chaos,
+        cancellation or deadlines that fails the run."""
+        from repro.launch.serve import main
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        argv = ["--reduced", "--requests", "2", "--prompt-len", "40",
+                "--max-new", "2", "--max-seq", "64", "--paged",
+                "--block-page", "16", "--block-budget", "2"]
+        with pytest.raises(SystemExit) as exc:
+            main(argv)
+        assert exc.value.code not in (0, None)
+        assert "2 of 2 requests did not finish" in str(exc.value.code)
+
+
 def test_write_token_kv_oob_drop_negative_positions():
     """The sharded decode write hands every shard the same token with
     shard-local positions: anything outside [0, S) — including *negative*

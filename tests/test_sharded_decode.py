@@ -46,9 +46,8 @@ from repro.kernels.dispatch import dispatch
 from repro.runtime import ExecPolicy
 
 def mesh2x4():
-    kw = ({{"axis_types": (jax.sharding.AxisType.Auto,) * 2}}
-          if hasattr(jax.sharding, "AxisType") else {{}})
-    return jax.make_mesh((2, 4), ("data", "model"), **kw)
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def qkv(b, h, hkv, d, smax, layout, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
