@@ -1,0 +1,11 @@
+"""Model step, decode: device time of one decode program execution, from
+the trace (program executions matched by name)."""
+
+PROGRAM = r"^jit_decode_fn\("
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    sec, n = run.trace.program_time(PROGRAM)
+    return 1e3 * sec / n if n else None

@@ -1,0 +1,8 @@
+"""Device: share of the traced stretch in which no program ran on the
+chip (1 - union of program execution intervals / stretch length)."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
