@@ -161,8 +161,9 @@ def attention_flash(q, k, v, *, causal=True, window=None, exp_impl="vexp",
         s = jnp.where(keep[:, None, None], s, NEG_INF)
         m_blk = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_blk)
-        alpha = exp_fn(m - m_new)
-        p = exp_fn(s - m_new[..., None])
+        with jax.named_scope("exp"):
+            alpha = exp_fn(m - m_new)
+            p = exp_fn(s - m_new[..., None])
         p = jnp.where(keep[:, None, None], p, 0.0)
         l_new = l * alpha + jnp.sum(p, axis=-1)
         acc_new = acc * alpha[..., None] + jnp.einsum(

@@ -47,7 +47,8 @@ def softmax(x: jax.Array, axis: int = -1, *, exp_impl: str | Callable = "vexp",
         x = jnp.where(where, x, -jnp.inf)
     m = jax.lax.stop_gradient(jnp.max(x, axis=axis, keepdims=True))
     m = jnp.where(jnp.isfinite(m), m, 0.0)  # all-masked rows
-    e = exp_fn(x - m)
+    with jax.named_scope("exp"):
+        e = exp_fn(x - m)
     if where is not None:
         e = jnp.where(where, e, 0.0)
     s = jnp.sum(e, axis=axis, keepdims=True)

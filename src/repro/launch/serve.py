@@ -82,6 +82,18 @@ poisoned slots at finish instead of streaming garbage; and a hysteretic
 degradation ladder sheds load under sustained pool pressure (L1 halves
 the prefill chunk width, L2 drops ``--degrade-groups`` to the policy's
 ``degrade_exp_backend``), restoring when pressure clears.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, on the profiler's own
+clock, so a device idle gap can be put down to what the host was doing):
+``serve.admit`` (a monolithic wave, from leaving the queue to the end of
+its rows' bookkeeping; ``rows``, ``bucket``, ``pool_rows``,
+``prompt_tokens``, ``queue_wait_ms``) holding ``serve.admit.wait`` (the
+prefill sync; ``runahead``), ``serve.decode.dispatch`` (``live``), and
+``serve.finish`` (``tokens``) holding ``serve.finish.wait`` (the token
+gather; ``runahead``). ``_Group.runahead`` counts decode dispatches since
+the host last waited on the device; ``Request.t_admit`` stamps when a
+request left the queue. With no profile active a span costs about a
+microsecond.
 """
 
 from __future__ import annotations
@@ -96,6 +108,7 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.registry import hot_path
 from repro.configs import get_config
@@ -144,6 +157,7 @@ class Request:
     finish_reason: Optional[str] = None
     # wall-clock latency markers (filled by the engine)
     t_submit: float = 0.0
+    t_admit: float = 0.0                # left the queue (0 while queued)
     t_first: float = 0.0
     t_done: float = 0.0
     # ---- lifecycle ----
@@ -205,9 +219,9 @@ class _Group:
         self.live_dev = self.state.place_tokens(
             jnp.zeros((max_batch,), jnp.int32))
         self.decode_steps = 0
-        self.decode_s: list = []    # per-step *dispatch* wall time (async:
-                                    # compute overlaps; see req_lat for real
-                                    # latency, measured at the finish sync)
+        self.runahead = 0           # decode dispatches (steps or bursts)
+                                    # since the host last waited on the
+                                    # device
         self.admit_s: list = []     # per-wave admission (prefill) wall time
         self.req_lat: list = []     # per-request submit->done wall latency
         # ---- chunked prefill (policy.prefill_chunk > 0) ----
@@ -221,9 +235,9 @@ class _Group:
                         if policy.prefill_chunk
                         and self.state.supports_chunked() else 0)
         self.prefilling: dict = {}  # slot -> (Request, cursor tokens cached)
-        self.chunk_s: list = []     # per-chunk *dispatch* wall time (async,
-                                    # like decode_s; real first-token latency
-                                    # is ttft below)
+        self.chunk_s: list = []     # per-chunk *dispatch* wall time (async:
+                                    # real first-token latency is ttft
+                                    # below)
         self.ttft: list = []        # submit -> first-token-dispatch wall
                                     # time, sampled at scheduling events only
         self.peak_logical = 0       # max summed live tokens (paged bench)
@@ -403,7 +417,7 @@ class _Group:
                 self.shed += 1
             else:
                 r.out.clear()
-                r.t_first = 0.0
+                r.t_admit = r.t_first = 0.0
                 self.requeued += 1
                 self.queue.appendleft(r)
         self.sweep()
@@ -502,6 +516,7 @@ class _Group:
                     head_h = h
             if bucket is None:
                 bucket = b
+            r.t_admit = time.perf_counter()
             take.append((free.pop(0), self.queue.popleft()))
         return take, bucket
 
@@ -532,66 +547,76 @@ class _Group:
                 # spinning the drain loop forever on an unservable head.
                 self._admit_backoff()
             return
-        slots = np.array([j for j, _ in take])
-        # prefill always runs at the full pool width so admitting 1 or
-        # max_batch requests hits the same executable per length bucket;
-        # rows without an admitted request are dummies (length-1, ignored).
-        toks = np.zeros((self.max_batch, sp), np.int32)
-        plens = np.ones(self.max_batch, np.int32)
-        for j, r in take:
-            toks[j, :len(r.prompt)] = r.prompt
-            plens[j] = len(r.prompt)
-        full = len(take) == self.max_batch
-        uniform = (full and all(len(r.prompt) == sp for _, r in take)
-                   and self.policy.kernel_backend != "pallas")
-        # uniform exact-bucket wave: no padding exists, skip the mask.
-        # (Not under a pallas policy: the ragged path runs under
-        # masked_policy, on the reference scan, so the fast path would
-        # prefill through a different implementation than solo serving
-        # and could flip a near-tie greedy argmax.)
-        t0 = time.perf_counter()
-        try:
-            first = self.state.prefill_into(slots, toks, plens, full=full,
-                                            uniform=uniform)
-        except OutOfBlocks:
-            # The admission gate debits fresh need AND pinned evictable
-            # supply per row, so absent injected faults this is
-            # unreachable by construction — but a failed allocation must
-            # never crash the server. prefill_into released every page
-            # the wave held; re-queue it in FIFO order and let the one
-            # bounded-retry policy decide (retry next tick with work in
-            # flight; bounded backoff then shed with nothing in flight).
-            for _, r in reversed(take):
-                self.queue.appendleft(r)
-            self._admit_backoff()
-            return
-        jax.block_until_ready(first)
-        self._admit_fail = 0
-        self.admit_s.append(time.perf_counter() - t0)
-        if full:
-            self.last = first
-        else:
-            self.last = self.last.at[slots].set(first[slots])
-        # one batched device-side liveness update per admission wave
-        self.live_dev = self.live_dev.at[jnp.asarray(slots)].set(1)
-        if self.spec_k:
-            # seed the device emission budget (tokens after the first);
-            # verify bursts decrement it by the true acceptance length
-            self.rem_dev = self.rem_dev.at[jnp.asarray(slots)].set(
-                jnp.asarray([r.max_new - 1 for _, r in take], jnp.int32))
-        now = time.perf_counter()
-        for j, r in take:
-            self.reqs[j] = r
-            self.lens[j] = len(r.prompt)
-            self.ntok[j] = 1
-            self._toks[j] = [first]
-            r.t_first = now
-            self.ttft.append(now - r.t_submit)
-            if admit_log is not None:
-                admit_log.append(r.rid)
-            if self.ntok[j] >= r.max_new:
-                self._finish(j, "max_new")
-        self._bump_peaks()
+        with TraceAnnotation(
+                "serve.admit", rows=len(take), bucket=sp,
+                pool_rows=self.max_batch,
+                prompt_tokens=sum(len(r.prompt) for _, r in take),
+                queue_wait_ms=1e3 * sum(r.t_admit - r.t_submit
+                                        for _, r in take)):
+            slots = np.array([j for j, _ in take])
+            # prefill always runs at the full pool width so admitting 1 or
+            # max_batch requests hits the same executable per length bucket;
+            # rows without an admitted request are dummies (length-1, ignored).
+            toks = np.zeros((self.max_batch, sp), np.int32)
+            plens = np.ones(self.max_batch, np.int32)
+            for j, r in take:
+                toks[j, :len(r.prompt)] = r.prompt
+                plens[j] = len(r.prompt)
+            full = len(take) == self.max_batch
+            uniform = (full and all(len(r.prompt) == sp for _, r in take)
+                       and self.policy.kernel_backend != "pallas")
+            # uniform exact-bucket wave: no padding exists, skip the mask.
+            # (Not under a pallas policy: the ragged path runs under
+            # masked_policy, on the reference scan, so the fast path would
+            # prefill through a different implementation than solo serving
+            # and could flip a near-tie greedy argmax.)
+            t0 = time.perf_counter()
+            try:
+                first = self.state.prefill_into(slots, toks, plens, full=full,
+                                                uniform=uniform)
+            except OutOfBlocks:
+                # The admission gate debits fresh need AND pinned evictable
+                # supply per row, so absent injected faults this is
+                # unreachable by construction — but a failed allocation must
+                # never crash the server. prefill_into released every page
+                # the wave held; re-queue it in FIFO order and let the one
+                # bounded-retry policy decide (retry next tick with work in
+                # flight; bounded backoff then shed with nothing in flight).
+                for _, r in reversed(take):
+                    r.t_admit = 0.0
+                    self.queue.appendleft(r)
+                self._admit_backoff()
+                return
+            # the prefill sync also waits for every decode step still queued
+            with TraceAnnotation("serve.admit.wait", runahead=self.runahead):
+                jax.block_until_ready(first)
+            self.runahead = 0
+            self._admit_fail = 0
+            self.admit_s.append(time.perf_counter() - t0)
+            if full:
+                self.last = first
+            else:
+                self.last = self.last.at[slots].set(first[slots])
+            # one batched device-side liveness update per admission wave
+            self.live_dev = self.live_dev.at[jnp.asarray(slots)].set(1)
+            if self.spec_k:
+                # seed the device emission budget (tokens after the first);
+                # verify bursts decrement it by the true acceptance length
+                self.rem_dev = self.rem_dev.at[jnp.asarray(slots)].set(
+                    jnp.asarray([r.max_new - 1 for _, r in take], jnp.int32))
+            now = time.perf_counter()
+            for j, r in take:
+                self.reqs[j] = r
+                self.lens[j] = len(r.prompt)
+                self.ntok[j] = 1
+                self._toks[j] = [first]
+                r.t_first = now
+                self.ttft.append(now - r.t_submit)
+                if admit_log is not None:
+                    admit_log.append(r.rid)
+                if self.ntok[j] >= r.max_new:
+                    self._finish(j, "max_new")
+            self._bump_peaks()
 
     # --------------------------------------------------- chunked admission
 
@@ -616,6 +641,7 @@ class _Group:
                     # released only by _chunk_done -> eventual finish, by
                     # reap/abort_chunk, or — if publishing the slot to
                     # the prefilling map itself fails — right here.
+                    r.t_admit = time.perf_counter()
                     self.prefilling[j] = (self.queue.popleft(), cur)
                 except BaseException:
                     self.state.abort_chunk(j)
@@ -643,8 +669,8 @@ class _Group:
         call per tick: each prefilling row contributes its next
         ``clens[j] <= chunk_c`` prompt tokens at its cursor; every other
         row rides along inert (``clens == 0``). Fully async — the chunk
-        is dispatched, never synced (chunk_s records dispatch wall time,
-        exactly like decode_s), so the host runs ahead and XLA pipelines
+        is dispatched, never synced (chunk_s records dispatch wall
+        time), so the host runs ahead and XLA pipelines
         chunk and decode steps back to back."""
         if not self.prefilling:
             return
@@ -738,12 +764,12 @@ class _Group:
         # slot's state before it is read again). Positions live on device
         # (live slots advance by +1 inside the donated program), so the
         # hot loop ships nothing host->device and syncs on nothing.
-        t0 = time.perf_counter()
         try:
             if self.injector is not None and \
                     self.injector.fire("decode.step_error"):
                 raise InjectedFault("decode dispatch failed")
-            nxt = self.state.step(self.last, self.live_dev)
+            with TraceAnnotation("serve.decode.dispatch", live=len(live)):
+                nxt = self.state.step(self.last, self.live_dev)
         except InjectedFault:
             # The chaos harness's failed dispatch: the donated carry must
             # be presumed consumed, so rebuild the pool and re-queue the
@@ -753,8 +779,8 @@ class _Group:
             self._recover_step_fault()
             return
         self.last = nxt
-        self.decode_s.append(time.perf_counter() - t0)
         self.decode_steps += 1
+        self.runahead += 1
         for j in live:
             self.lens[j] += 1
             self.ntok[j] += 1
@@ -781,7 +807,6 @@ class _Group:
         if self.injector is not None and \
                 self.injector.fire("decode.poison"):
             self.state.poison_slot(self.injector.choose(live))
-        t0 = time.perf_counter()
         try:
             if self.injector is not None and \
                     self.injector.fire("decode.step_error"):
@@ -803,8 +828,8 @@ class _Group:
             self._recover_step_fault()
             return
         self.last = nlast
-        self.decode_s.append(time.perf_counter() - t0)
         self.decode_steps += 1
+        self.runahead += 1
         cap = self.state.max_len()
         w = self.spec_k + 1
         for j in live:
@@ -834,6 +859,7 @@ class _Group:
         room remain), so settling cannot spin."""
         r = self.reqs[j]
         col = np.asarray(jnp.concatenate(self._toks[j], axis=1))[j]
+        self.runahead = 0
         col = col[col != SPEC_PAD]
         n = int(col.size)
         pos = len(r.prompt) + n - 1     # cache rows the slot holds
@@ -852,49 +878,54 @@ class _Group:
         # scheduling events, so sampling the peak just before a slot
         # releases (plus at admission/stats) is exact — and keeps the
         # decode hot loop free of per-step host accounting.
-        self._bump_peaks()
-        r = self.reqs[j]
-        # one device->host sync per finished request: gather its column
-        # from the logged per-step argmax vectors / per-burst accepted
-        # blocks (speculative groups; SPEC_PAD marks lanes past each
-        # burst's accepted length and is filtered out here).
-        toks = np.asarray(jnp.concatenate(self._toks.pop(j), axis=1))[j]
-        toks = toks[toks != SPEC_PAD]
-        if self.spec_k:
-            b = int(self._bursts[j])
-            self._bursts[j] = 0
-            self.spec_bursts += b
-            self.spec_drafted += b * self.spec_k
-            # every burst that emitted anything spent one bonus token;
-            # the rest of the column is accepted draft proposals
-            acc = min(max(0, len(toks) - 1 - b), b * self.spec_k)
-            self.spec_accepted += acc
-            self.spec_rolled_back += b * self.spec_k - acc
-            self.rem_dev = self.rem_dev.at[j].set(0)
-        if (toks < 0).any():
-            # the decode programs' sticky finite-logits sentinel: some
-            # step saw non-finite logits for this row. Quarantine — never
-            # stream the garbage — and scrub the slot (deep zero, not a
-            # plain reset: surviving NaN rows would contaminate the next
-            # occupant through additively-masked attention). Detection
-            # costs nothing extra: the token column was already
-            # materialized here.
-            self.reqs[j] = None
+        cols = self._toks.pop(j)
+        with TraceAnnotation("serve.finish",
+                             tokens=sum(c.shape[1] for c in cols)):
+            self._bump_peaks()
+            r = self.reqs[j]
+            # one device->host sync per finished request: gather its column
+            # from the logged per-step argmax vectors / per-burst accepted
+            # blocks (speculative groups; SPEC_PAD marks lanes past each
+            # burst's accepted length and is filtered out here).
+            with TraceAnnotation("serve.finish.wait", runahead=self.runahead):
+                toks = np.asarray(jnp.concatenate(cols, axis=1))[j]
+            self.runahead = 0
+            toks = toks[toks != SPEC_PAD]
+            if self.spec_k:
+                b = int(self._bursts[j])
+                self._bursts[j] = 0
+                self.spec_bursts += b
+                self.spec_drafted += b * self.spec_k
+                # every burst that emitted anything spent one bonus token;
+                # the rest of the column is accepted draft proposals
+                acc = min(max(0, len(toks) - 1 - b), b * self.spec_k)
+                self.spec_accepted += acc
+                self.spec_rolled_back += b * self.spec_k - acc
+                self.rem_dev = self.rem_dev.at[j].set(0)
+            if (toks < 0).any():
+                # the decode programs' sticky finite-logits sentinel: some
+                # step saw non-finite logits for this row. Quarantine — never
+                # stream the garbage — and scrub the slot (deep zero, not a
+                # plain reset: surviving NaN rows would contaminate the next
+                # occupant through additively-masked attention). Detection
+                # costs nothing extra: the token column was already
+                # materialized here.
+                self.reqs[j] = None
+                self.live_dev = self.live_dev.at[j].set(0)
+                self.state.scrub_slot(j)
+                self._finish_host(r, "quarantined")
+                self.sweep()
+                return
+            r.out.extend(int(t) for t in toks)
+            r.finish_reason = reason
+            r.t_done = time.perf_counter()   # after the sync: true completion
+            self.req_lat.append(r.t_done - r.t_submit)
+            self.reqs[j] = None          # slot freed; next admit() reuses it
+            # park the slot device-side: live=0 excludes it from position
+            # advance, and the state resets the slot (recurrent h/conv is
+            # read unconditionally — a stale occupant must not bleed).
             self.live_dev = self.live_dev.at[j].set(0)
-            self.state.scrub_slot(j)
-            self._finish_host(r, "quarantined")
-            self.sweep()
-            return
-        r.out.extend(int(t) for t in toks)
-        r.finish_reason = reason
-        r.t_done = time.perf_counter()   # after the sync: true completion
-        self.req_lat.append(r.t_done - r.t_submit)
-        self.reqs[j] = None          # slot freed; next admit() reuses it
-        # park the slot device-side: live=0 excludes it from position
-        # advance, and the state resets the slot (recurrent h/conv is
-        # read unconditionally — a stale occupant must not bleed).
-        self.live_dev = self.live_dev.at[j].set(0)
-        self.state.reset_slots([j])
+            self.state.reset_slots([j])
 
     @property
     def busy(self) -> bool:

@@ -75,11 +75,12 @@ def attn_apply(x, p, cfg, pos, *, window=None, causal=None, kv_valid=None,
     q, k, v = _qkv(x, p, cfg, pos)
     if kv_valid is not None:
         policy = masked_policy(policy)
-    o = attention(q, k, v, causal=causal, window=window,
-                  exp_impl=cfg.exp_impl, impl=cfg.attention_impl,
-                  unroll=cfg.unroll_scans, block_k=cfg.attn_block_k,
-                  mm_dtype=cfg.attn_mm_dtype, kv_valid=kv_valid,
-                  policy=policy)
+    with jax.named_scope("attn"):
+        o = attention(q, k, v, causal=causal, window=window,
+                      exp_impl=cfg.exp_impl, impl=cfg.attention_impl,
+                      unroll=cfg.unroll_scans, block_k=cfg.attn_block_k,
+                      mm_dtype=cfg.attn_mm_dtype, kv_valid=kv_valid,
+                      policy=policy)
     return o.reshape(x.shape[0], x.shape[1], -1) @ p["wo"], (k, v)
 
 
@@ -167,11 +168,14 @@ def attn_decode(x, p, cfg, cache_k, cache_v, pos, *, window=None,
         k = k.transpose(0, 2, 1, 3)          # (B, Hkv, 1, hd) — tiny
         v = v.transpose(0, 2, 1, 3)
     wp = pos if write_pos is None else write_pos
-    ck = _write_token_kv(cache_k, k, wp, lay, oob_drop=oob_drop)
-    cv = _write_token_kv(cache_v, v, wp, lay, oob_drop=oob_drop)
-    o = decode_attention(q, ck, cv, cache_len=pos + 1, window=window,
-                         exp_impl=cfg.exp_impl, mm_dtype=cfg.attn_mm_dtype,
-                         layout=lay, policy=policy)
+    with jax.named_scope("kv_write"):
+        ck = _write_token_kv(cache_k, k, wp, lay, oob_drop=oob_drop)
+        cv = _write_token_kv(cache_v, v, wp, lay, oob_drop=oob_drop)
+    with jax.named_scope("attn"):
+        o = decode_attention(q, ck, cv, cache_len=pos + 1, window=window,
+                             exp_impl=cfg.exp_impl,
+                             mm_dtype=cfg.attn_mm_dtype, layout=lay,
+                             policy=policy)
     return o.reshape(b, 1, -1) @ p["wo"], (ck, cv)
 
 
@@ -653,20 +657,26 @@ def decode_step(params, cfg, token, cache, pos, *, policy=None, live=None):
             k, v, q = _qkv_single(x, layer_p, cfg, pos)
             if cfg.kv_cache_layout == "bhsd":
                 k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            ck = _write_token_kv(ck, k, wpos, cfg.kv_cache_layout,
-                                 oob_drop=drop)
-            cv = _write_token_kv(cv, v, wpos, cfg.kv_cache_layout,
-                                 oob_drop=drop)
+            with jax.named_scope("kv_write"):
+                ck = _write_token_kv(ck, k, wpos, cfg.kv_cache_layout,
+                                     oob_drop=drop)
+                cv = _write_token_kv(cv, v, wpos, cfg.kv_cache_layout,
+                                     oob_drop=drop)
             h = norm_apply(x, layer_p["ln_attn"], cfg.norm, cfg.norm_eps)
-            y, _ = _decode_windowed(h, layer_p, cfg, ck, cv, pos, wpos,
-                                    policy=policy)
-            x = _finish_block(x, h, y, layer_p, cfg, policy=policy)
+            with jax.named_scope("attn"):
+                y, _ = _decode_windowed(h, layer_p, cfg, ck, cv, pos, wpos,
+                                        policy=policy)
+            with jax.named_scope("mlp"):
+                x = _finish_block(x, h, y, layer_p, cfg, policy=policy)
             return x, {"k": ck, "v": cv}
         h = norm_apply(x, layer_p["ln_attn"], cfg.norm, cfg.norm_eps)
+        # attn_decode scopes its cache write ("kv_write") and its
+        # attention ("attn")
         a, (ck, cv) = attn_decode(h, layer_p["attn"], cfg, ck, cv, pos,
                                   policy=policy, write_pos=wpos,
                                   oob_drop=drop)
-        x = _finish_block(x, h, a, layer_p, cfg, policy=policy)
+        with jax.named_scope("mlp"):
+            x = _finish_block(x, h, a, layer_p, cfg, policy=policy)
         return x, {"k": ck, "v": cv}
 
     x, cache = jax.lax.scan(body, x, (params["layers"],
