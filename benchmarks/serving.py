@@ -229,8 +229,7 @@ def _fixed_chunk_runner(cfg, params, lens, *, policy=None):
             toks = jnp.asarray(prompts[i:i + MAX_BATCH])
             b = toks.shape[0]
             logits, cache = prefill(params, toks)
-            ck = jnp.zeros((cfg.n_layers, b, MAX_SEQ, cfg.n_kv_heads,
-                            cfg.hd), jnp.bfloat16)
+            ck = api.init_cache(cfg, b, MAX_SEQ)["k"]
             ck = ck.at[:, :, :plen].set(cache["k"])
             cv = jnp.zeros_like(ck).at[:, :, :plen].set(cache["v"])
             cache = {"k": ck, "v": cv}

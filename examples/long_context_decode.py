@@ -37,8 +37,7 @@ def main():
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0, cfg.vocab)
     _, cache = api.prefill(params, cfg, {"tokens": toks})
-    ck = jnp.zeros((cfg.n_layers, b, smax, cfg.n_kv_heads, cfg.hd),
-                   jnp.bfloat16).at[:, :, :s].set(cache["k"])
+    ck = api.init_cache(cfg, b, smax)["k"].at[:, :, :s].set(cache["k"])
     cv = jnp.zeros_like(ck).at[:, :, :s].set(cache["v"])
     cache = {"k": ck, "v": cv}
     tok = toks[:, -1:]
@@ -47,8 +46,7 @@ def main():
 
     mesh = _mesh_2x4()
     with mesh:
-        cs = {"k": P(None, None, "model", None, None),
-              "v": P(None, None, "model", None, None)}
+        cs = {"k": P(None, None, "model"), "v": P(None, None, "model")}
         cc = jax.device_put(cache, shd.named(mesh, cs))
         pp = jax.device_put(params,
                             shd.named(mesh, shd.param_specs(cfg, mesh)))

@@ -245,8 +245,10 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
                      layout="bshd", policy=None):
     """Single-token decode attention over a (possibly sequence-sharded) cache.
 
-    q: (B, 1, H, D); caches: (B, S_max, Hkv, D); cache_len: scalar or (B,)
-    number of valid positions (the new token's K/V must already be written).
+    q: (B, 1, H, D); caches: (B, S_max, Hkv, D) or, as the serving pool
+    stores them, (B, S_max, Hkv*D) ("bshd"), or (B, Hkv, S_max, D)
+    ("bhsd"); cache_len: scalar or (B,) number of valid positions (the new
+    token's K/V must already be written).
 
     Written as pure max/sum reductions over the cache sequence axis so that a
     cache sharded along S lowers to partial (m, l, acc) per shard + a cheap
@@ -268,6 +270,9 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
                 sm_scale=sm_scale, layout=layout, policy=policy)
     exp_fn = _resolve(exp_impl)
     b, _, h, d = q.shape
+    if k_cache.ndim == 3:                  # lane-dense "bshd" pool layer
+        k_cache = k_cache.reshape(*k_cache.shape[:2], -1, d)
+        v_cache = v_cache.reshape(*v_cache.shape[:2], -1, d)
     if layout == "bhsd":
         hkv, smax = k_cache.shape[1], k_cache.shape[2]
     else:

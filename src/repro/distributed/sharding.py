@@ -179,8 +179,9 @@ def cache_specs(cfg, mesh, batch: int, *, kv_mode: str = "auto"):
                     "v": P(None, bspec, "model", None, None)}
         return {"k": P(None, bspec, None, seq, None),
                 "v": P(None, bspec, None, seq, None)}
-    return {"k": P(None, bspec, seq, None, None),
-            "v": P(None, bspec, seq, None, None)}
+    # "bshd": (L, B, S, Hkv*hd), heads folded into the lanes
+    return {"k": P(None, bspec, seq, None),
+            "v": P(None, bspec, seq, None)}
 
 
 def decode_kv_axis(cfg, mesh, batch: int, *, kv_mode: str = "auto"):
@@ -217,9 +218,8 @@ def serve_cache_sharding(cfg, mesh, seq_axis):
     hot path."""
     from repro.models.transformer import cache_seq_axis
     layout = getattr(cfg, "kv_cache_layout", "bshd")
-    spec = [None] * 5
-    spec[cache_seq_axis(layout, stacked=True)] = seq_axis
-    sh = NamedSharding(mesh, P(*spec))
+    s_ax = cache_seq_axis(layout, stacked=True)
+    sh = NamedSharding(mesh, P(*([None] * s_ax), seq_axis))
     return {"k": sh, "v": sh}
 
 

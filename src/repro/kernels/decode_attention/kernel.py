@@ -7,17 +7,19 @@ in its storage dtype (bf16) with f32 accumulation (``accum_dtype="bfloat16"``
 drops the scratch statistics to bf16 for the memory/accuracy trade the
 ExecPolicy exposes).
 
-Two cache layouts share one kernel body: head-major "bhsd" (B, Hkv, S, hd)
-— the §Perf C3 layout — and sequence-major "bshd" (B, S, Hkv, hd); the
-BlockSpec index maps place the KV-sweep axis wherever the layout stores it,
-so neither layout pays a materialized transpose.
+Two cache layouts share one kernel body: head-major "bhsd" (B, Hkv, S, d)
+— the §Perf C3 layout — and sequence-major "bshd" (B, S, nH * d) with the
+heads folded into the lanes; the BlockSpec index maps place the KV-sweep
+axis wherever the layout stores it, so neither layout pays a materialized
+transpose.
 
-Grid = (nB, Hkv, nS) with the KV sweep innermost; each program handles one
-KV head's query group (GQA: G = H // Hkv query rows) for a *block* of
-``block_b`` batch rows — decode dots are tiny (G × block_s), so batching
-rows into the block amortizes grid/DMA bookkeeping across the slot pool
-instead of paying it per row. ``block_b`` is clamped so the K/V blocks
-stay a few MB of VMEM.
+Grid = (nB, nH, nS) with the KV sweep innermost; each program handles one
+lane block's query rows (GQA: G = H // Hkv rows per KV head; a "bshd"
+block of 128 lanes holding several heads gets their rows block-diagonal,
+see ``ops._group_q``) for a *block* of ``block_b`` batch rows — decode
+dots are tiny (G × block_s), so batching rows into the block amortizes
+grid/DMA bookkeeping across the slot pool instead of paying it per row.
+``block_b`` is clamped so the K/V blocks stay a few MB of VMEM.
 
 ``cache_len`` is a per-batch-row (B,) vector in SMEM: each row of a block
 masks the KV sweep against its own length, so a continuous-batching server
@@ -31,7 +33,7 @@ primitive): in *partial* mode the kernel emits the raw per-shard
 sweep in **global** coordinates via ``seq_offset`` (an SMEM scalar: the
 absolute position of this shard's first cache row). *Packed* partial mode
 goes one step further and lands the statistics in ONE contiguous
-(B, Hkv, G, d+2) tile laid out ``[acc | m | l]`` — the exact buffer the
+(B, nH, G, d+2) tile laid out ``[acc | m | l]`` — the exact buffer the
 single-collective merge (``core.softmax.stats_merge_collective_packed``)
 all_gathers, so no stat array is ever concatenated outside the kernel.
 Shards are merged under ``shard_map`` per the policy's merge strategy —
@@ -176,8 +178,10 @@ def resolve_block_b(b: int, block_s: int, d: int) -> int:
 
 
 def _specs(layout: str, block_b: int, g: int, bs: int, d: int):
-    """(smem, q, k/v) BlockSpecs for the given layout; grid (nB, Hkv, nS).
-    "bshd" caches arrive as (B, S, Hkv * d) (see ``_kv_operand``)."""
+    """(smem, q, k/v) BlockSpecs for the given layout; grid (nB, nH, nS).
+    "bshd" caches arrive as (B, S, nH * d): one head's or lane block's
+    (bs, d) tile is lane-aligned, where a (bs, 1, d) block of a 4-D array
+    would break the (8, 128) tiling rule on its minor dims."""
     from jax.experimental.pallas import tpu as pltpu
     q_spec = pl.BlockSpec((block_b, 1, g, d),
                           lambda bb, hh, si: (bb, hh, 0, 0))
@@ -189,17 +193,6 @@ def _specs(layout: str, block_b: int, g: int, bs: int, d: int):
                                lambda bb, hh, si: (bb, si, hh))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return smem, q_spec, kv_spec
-
-
-def _kv_operand(layout: str, cache):
-    """The cache as the kernel reads it. A (B, S, Hkv, d) "bshd" cache is
-    viewed as (B, S, Hkv * d), so one head's block is a lane-aligned
-    (bs, d) tile: a (bs, 1, d) block of the 4-D array would break the
-    (8, 128) tiling rule on its (Hkv, d) minor dims."""
-    if layout == "bhsd":
-        return cache
-    b, s, hkv, d = cache.shape
-    return cache.reshape(b, s, hkv * d)
 
 
 def _scratch(block_b: int, g: int, d: int, accum_dtype: str):
@@ -220,13 +213,14 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, seq_offset, *,
                             exp_impl: str = "vexp",
                             window=None, layout: str = "bhsd",
                             accum_dtype: str = "float32"):
-    """q: (B, Hkv, G, d); caches: (B, Hkv, S, d) ("bhsd") or (B, S, Hkv, d)
-    ("bshd"); cache_len: (B,) int32 per-row valid lengths (broadcast a
-    scalar before calling); seq_offset: (1,) int32 absolute position of
-    this cache slice's first row (zero when unsharded); s_valid: unpadded
-    cache length (padded rows above it are never attended).
-    Returns (B, Hkv, G, d). S divisible by block_s, B by the row block;
-    d lane-padded — all handled by ops."""
+    """q: (B, nH, G, d) query rows per head or lane block; caches:
+    (B, nH, S, d) ("bhsd") or (B, S, nH * d) ("bshd"); cache_len: (B,)
+    int32 per-row valid lengths (broadcast a scalar before calling);
+    seq_offset: (1,) int32 absolute position of this cache slice's first
+    row (zero when unsharded); s_valid: unpadded cache length (padded rows
+    above it are never attended). Returns (B, nH, G, d). S divisible by
+    block_s, B by the row block; d a multiple of 128 — all handled by
+    ops."""
     b, hkv, g, d = q.shape
     smax = k_cache.shape[2] if layout == "bhsd" else k_cache.shape[1]
     bs = min(block_s, smax)
@@ -246,8 +240,7 @@ def decode_attention_kernel(q, k_cache, v_cache, cache_len, seq_offset, *,
                                lambda bb_, hh, si: (bb_, hh, 0, 0)),
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
-      _kv_operand(layout, v_cache))
+    )(cache_len, seq_offset, q, k_cache, v_cache)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -262,7 +255,7 @@ def decode_attention_kernel_partial(q, k_cache, v_cache, cache_len,
                                     window=None, layout: str = "bhsd",
                                     accum_dtype: str = "float32"):
     """Partial-statistics mode: same sweep, but emits the shard's raw
-    (m, l, acc) — shapes (B, Hkv, G, 1) ×2 and (B, Hkv, G, d), all f32 —
+    (m, l, acc) — shapes (B, nH, G, 1) ×2 and (B, nH, G, d), all f32 —
     with masking done in *global* positions (``seq_offset`` + local index
     against the global ``cache_len``). A shard whose slice lies entirely
     outside [cache_len - window, cache_len) returns the merge identity
@@ -292,8 +285,7 @@ def decode_attention_kernel_partial(q, k_cache, v_cache, cache_len,
                                 lambda bb_, hh, si: (bb_, hh, 0, 0))],
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
-      _kv_operand(layout, v_cache))
+    )(cache_len, seq_offset, q, k_cache, v_cache)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -309,7 +301,7 @@ def decode_attention_kernel_packed(q, k_cache, v_cache, cache_len,
                                    accum_dtype: str = "float32"):
     """Packed partial-statistics mode: the same sweep as
     ``decode_attention_kernel_partial`` but the shard's raw statistics
-    land in ONE contiguous f32 tile of shape (B, Hkv, G, d + 2), laid out
+    land in ONE contiguous f32 tile of shape (B, nH, G, d + 2), laid out
     ``[acc | m | l]`` along the last axis — the unit the single-collective
     merge (``core.softmax.stats_merge_collective_packed``) all_gathers.
     The two stat lanes ride beyond ``d``; the merge slices them off after
@@ -333,8 +325,7 @@ def decode_attention_kernel_packed(q, k_cache, v_cache, cache_len,
                                lambda bb_, hh, si: (bb_, hh, 0, 0)),
         scratch_shapes=_scratch(bb, g, d, accum_dtype),
         interpret=interpret,
-    )(cache_len, seq_offset, q, _kv_operand(layout, k_cache),
-      _kv_operand(layout, v_cache))
+    )(cache_len, seq_offset, q, k_cache, v_cache)
 
 
 def decode_attention_bhsd(q, k_cache, v_cache, cache_len, *, sm_scale: float,

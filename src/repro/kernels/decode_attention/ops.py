@@ -8,6 +8,13 @@ serving engine produces — head-major ("bhsd") *and* sequence-major
 ("bshd") caches, scalar or per-slot (B,) ``cache_len``, and sliding
 windows — with no reference fallback.
 
+A sequence-major cache is read as the pool stores it, (B, S, Hkv*d) with
+the heads folded into the lanes. Where d divides 128 one 128-lane block
+holds 128 // d heads and their queries arrive block-diagonal
+(``_group_q``), so the kernel reads the cache with no copy; a head dim
+that does not divide 128 (and every "bhsd" cache) is lane-padded per
+head, which copies the cache on each call.
+
 ``decode_attention_sharded`` is the sequence-parallel entry: a KV cache
 sharded along its sequence axis over a mesh axis is swept shard-locally in
 partial-statistics mode (each shard masks against its own slice of the
@@ -49,27 +56,112 @@ def _seq_axis(layout: str) -> int:
     return 2 if layout == "bhsd" else 1
 
 
+def _heads_per_block(d: int, hkv: int) -> int:
+    """Heads one 128-lane block of a sequence-major cache holds, as the
+    kernel reads it unpadded: 1 where ``d`` fills whole lane tiles,
+    ``128 // d`` where ``d`` divides 128 and the heads fill whole blocks,
+    0 where neither holds (the cache is then lane-padded per head)."""
+    if d % 128 == 0:
+        return 1
+    if 128 % d == 0 and hkv % (128 // d) == 0:
+        return 128 // d
+    return 0
+
+
+def _group_q(qg, hp: int):
+    """(B, Hkv, G, d) queries -> (B, Hkv/hp, hp*G, hp*d) block-diagonal
+    rows: row j*G + g of lane block n holds head n*hp + j's query in lanes
+    [j*d, (j+1)*d) and zeros elsewhere, so one dot against the block's
+    K scores every head on its own lanes only. Built from whole rows
+    repeated along the lanes and a mask, like ``_ungroup`` without a
+    reshape that splits the lanes into (hp, d)."""
+    b, hkv, g, d = qg.shape
+    q = qg.reshape(b, hkv // hp, hp * g, d)
+    q = jnp.concatenate([q] * hp, axis=-1)
+    row = jax.lax.broadcasted_iota(jnp.int32, q.shape, 2)
+    lane = jax.lax.broadcasted_iota(jnp.int32, q.shape, 3)
+    return jnp.where(lane // d == row // g, q, jnp.zeros((), q.dtype))
+
+
+def _ungroup(x, hp: int, d: int):
+    """Kernel rows (B, Hkv/hp, hp*G, lanes) back to (B, Hkv, G, d): each
+    row keeps its own head's lanes (lane-padded rows drop the pad).
+
+    Plain slices of the 4-D rows: on TPU, XLA folded a reshape of the
+    lanes into (hp, d) followed by these slices into one bitcast that
+    keeps lanes [0, d) of every row, so every second head came out
+    wrong (``tests/test_tpu_compile.py`` checks the compiled form)."""
+    if hp == 1:
+        return x[..., :d]
+    b, nb, rows, _ = x.shape
+    g = rows // hp
+    x = jnp.stack([x[:, :, j * g:(j + 1) * g, j * d:(j + 1) * d]
+                   for j in range(hp)], axis=2)       # (B, nb, hp, G, d)
+    return x.reshape(b, nb * hp, g, d)
+
+
+def _kv_heads(q, k_cache, layout) -> int:
+    if layout == "bhsd":
+        return k_cache.shape[1]
+    if k_cache.ndim == 3:                  # (B, S, Hkv*d), heads in lanes
+        return k_cache.shape[2] // q.shape[-1]
+    return k_cache.shape[2]
+
+
+def _lane_heads(q, k_cache, layout) -> int:
+    """Heads per kernel row block: ``_heads_per_block`` for a "bshd"
+    cache the kernel reads unpadded, else 1 (one lane-padded head)."""
+    if layout == "bhsd":
+        return 1
+    return _heads_per_block(q.shape[-1], _kv_heads(q, k_cache, layout)) or 1
+
+
 def _prepare(q, k_cache, v_cache, cache_len, block_s, layout):
-    """Group queries, lane-pad d, block-pad S, broadcast cache_len."""
+    """Queries grouped as the kernel reads them, the cache viewed as it
+    reads it, S block-padded, cache_len broadcast to (B,).
+
+    A "bshd" cache is (B, S, Hkv*d) as the pool stores it, or (B, S, Hkv,
+    d); the kernel reads (B, S, lanes) in 128-lane blocks. Where
+    ``_heads_per_block`` is nonzero it reads the cache as it is and the
+    queries come block-diagonal (``_group_q``); otherwise, and for
+    "bhsd", each head is lane-padded to a multiple of 128 (a copy of the
+    cache). Returns (q, k, v, cache_len, smax, hp); ``_ungroup`` maps
+    the kernel's rows back to heads."""
     b, _, h, d = q.shape
-    hkv = k_cache.shape[1] if layout == "bhsd" else k_cache.shape[2]
     s_ax = _seq_axis(layout)
     smax = k_cache.shape[s_ax]
-    g = h // hkv
-    qg = q.reshape(b, hkv, g, d)
-    d_pad = -(-d // 128) * 128
-    s_pad = -(-smax // min(block_s, smax)) * min(block_s, smax)
+    hkv = _kv_heads(q, k_cache, layout)
+    qg = q.reshape(b, hkv, h // hkv, d)
+    hp = _heads_per_block(d, hkv) if layout == "bshd" else 0
+    if hp:
+        qp = qg if hp == 1 else _group_q(qg, hp)
 
-    def pad(x):
-        pads = [(0, 0)] * 4
-        pads[s_ax] = (0, s_pad - x.shape[s_ax])
-        pads[3] = (0, d_pad - x.shape[3])
+        def lanes(x):
+            return x.reshape(b, smax, hkv * d)
+    else:
+        hp, d_pad = 1, -(-d // 128) * 128
+        qp = jnp.pad(qg, [(0, 0)] * 3 + [(0, d_pad - d)])
+
+        def lanes(x):
+            if layout == "bhsd":
+                return jnp.pad(x, [(0, 0)] * 3 + [(0, d_pad - d)])
+            x = jnp.pad(x.reshape(b, smax, hkv, d),
+                        [(0, 0)] * 3 + [(0, d_pad - d)])
+            return x.reshape(b, smax, hkv * d_pad)
+    bs = min(block_s, smax)
+    s_pad = -(-smax // bs) * bs
+
+    def view(x):
+        x = lanes(x)
+        if s_pad == smax:
+            return x
+        pads = [(0, 0)] * x.ndim
+        pads[s_ax] = (0, s_pad - smax)
         return jnp.pad(x, pads)
 
-    qp = jnp.pad(qg, [(0, 0), (0, 0), (0, 0), (0, d_pad - d)])
     clen = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1),
                             (b,))
-    return qp, pad(k_cache), pad(v_cache), clen, smax
+    return qp, view(k_cache), view(v_cache), clen, smax, hp
 
 
 def _policy_kernel_args(policy: Optional[ExecPolicy], block_s, interpret):
@@ -92,21 +184,22 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
                      sm_scale=None, layout="bhsd", block_s=512,
                      interpret=None, policy: Optional[ExecPolicy] = None):
     """Fused flash-decode. q: (B, 1, H, d); caches: (B, Hkv, S, d) ("bhsd")
-    or (B, S, Hkv, d) ("bshd"); cache_len: scalar int32 or per-row (B,)
-    int32 of valid positions (the serving engine's per-slot lengths);
-    ``window``: static sliding window (attend exactly the last ``window``
-    positions of each row's valid range). Returns (B, 1, H, d)."""
+    or (B, S, Hkv*d) / (B, S, Hkv, d) ("bshd"); cache_len: scalar int32
+    or per-row (B,) int32 of valid positions (the serving engine's
+    per-slot lengths); ``window``: static sliding window (attend exactly
+    the last ``window`` positions of each row's valid range). Returns
+    (B, 1, H, d)."""
     exp_impl, accum, block_s, interpret = _policy_kernel_args(
         policy, block_s, interpret)
     b, _, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax = _prepare(q, k_cache, v_cache, cache_len,
-                                      block_s, layout)
+    qp, kp, vp, clen, smax, hp = _prepare(q, k_cache, v_cache, cache_len,
+                                          block_s, layout)
     out = decode_attention_kernel(
         qp, kp, vp, clen, jnp.zeros((1,), jnp.int32), sm_scale=scale,
         s_valid=smax, block_s=block_s, interpret=interpret,
         exp_impl=exp_impl, window=window, layout=layout, accum_dtype=accum)
-    return out[..., :d].reshape(b, 1, h, d)
+    return _ungroup(out, hp, d).reshape(b, 1, h, d)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "sm_scale", "layout",
@@ -128,14 +221,16 @@ def decode_attention_partial(q, k_cache, v_cache, cache_len, seq_offset, *,
         policy, block_s, interpret)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax = _prepare(q, k_cache, v_cache, cache_len,
-                                      block_s, layout)
+    qp, kp, vp, clen, smax, hp = _prepare(q, k_cache, v_cache, cache_len,
+                                          block_s, layout)
     off = jnp.asarray(seq_offset, jnp.int32).reshape(1)
     m, l, acc = decode_attention_kernel_partial(
         qp, kp, vp, clen, off, sm_scale=scale, s_valid=smax,
         block_s=block_s, interpret=interpret, exp_impl=exp_impl,
         window=window, layout=layout, accum_dtype=accum)
-    return m, l, acc[..., :d]
+    acc = _ungroup(acc, hp, d)
+    stat = acc.shape[:3] + (1,)           # row statistics, one per head
+    return m.reshape(stat), l.reshape(stat), acc
 
 
 @functools.partial(jax.jit, static_argnames=("window", "sm_scale", "layout",
@@ -149,18 +244,19 @@ def decode_attention_partial_packed(q, k_cache, v_cache, cache_len,
     """Per-shard partial statistics as ONE contiguous packed tile.
 
     Same sweep as ``decode_attention_partial`` but the kernel writes the
-    shard's raw statistics directly into a single f32 buffer of shape
-    (B, Hkv, G, d_pad + 2) laid out ``[acc | m | l]`` — the unit the
-    single-collective merge all_gathers whole. ``d_pad`` is the
-    lane-padded head dim; merge first, then slice the accumulator back to
-    the true ``d`` (the padded lanes are zeros and fold to zeros).
+    shard's raw statistics directly into a single f32 buffer of the
+    kernel's rows, (B, Hkv/hp, hp*G, lanes + 2) laid out
+    ``[acc | m | l]`` — the unit the single-collective merge all_gathers
+    whole. Rows merge independently; merge first, then map the
+    accumulator back to heads with ``_ungroup`` (``_lane_heads`` gives
+    hp; lanes outside a row's own head fold like any other).
     """
     exp_impl, accum, block_s, interpret = _policy_kernel_args(
         policy, block_s, interpret)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax = _prepare(q, k_cache, v_cache, cache_len,
-                                      block_s, layout)
+    qp, kp, vp, clen, smax, _ = _prepare(q, k_cache, v_cache, cache_len,
+                                         block_s, layout)
     off = jnp.asarray(seq_offset, jnp.int32).reshape(1)
     return decode_attention_kernel_packed(
         qp, kp, vp, clen, off, sm_scale=scale, s_valid=smax,
@@ -199,14 +295,15 @@ def decode_attention_partial_merged(q, k_cache, v_cache, cache_len,
             sm_scale=sm_scale, layout=layout, policy=policy)
         stats, acc = stats_merge_collective_packed(packed, seq_axis,
                                                    exp_fn=exp_fn)
-        acc = acc[..., :d]
+        out = _ungroup(acc * (1.0 / jnp.maximum(stats.l, 1e-30)),
+                       _lane_heads(q, k_cache, layout), d)
     else:
         m, l, acc = decode_attention_partial(
             q, k_cache, v_cache, cache_len, seq_offset, window=window,
             sm_scale=sm_scale, layout=layout, policy=policy)
         stats, acc = stats_merge_collective(
             SoftmaxStats(m=m, l=l), acc, seq_axis, exp_fn=exp_fn)
-    out = acc * (1.0 / jnp.maximum(stats.l, 1e-30))
+        out = acc * (1.0 / jnp.maximum(stats.l, 1e-30))
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
@@ -218,9 +315,7 @@ def _sharded_program(mesh, seq_axis, window, sm_scale, layout: str,
     from jax.sharding import PartitionSpec as P
 
     s_ax = _seq_axis(layout)
-    kv_spec = [None] * 4
-    kv_spec[s_ax] = seq_axis
-    kv_spec = P(*kv_spec)
+    kv_spec = P(*([None] * s_ax), seq_axis)
 
     def _local(q, k, v, cl):
         local_s = k.shape[s_ax]

@@ -889,12 +889,11 @@ class KVDecodeState(DecodeState):
             return policy
         from repro.kernels.dispatch import dispatch, autotune_policy
         lay = cfg.kv_cache_layout
-        kv_shape = ((self.pool_width, cfg.n_kv_heads, self.cache_s, cfg.hd)
-                    if lay == "bhsd" else
-                    (self.pool_width, self.cache_s, cfg.n_kv_heads, cfg.hd))
+        pool = jax.eval_shape(
+            lambda: api.init_cache(cfg, self.pool_width, self.cache_s))["k"]
         q = jnp.zeros((self.pool_width, 1, cfg.n_heads, cfg.hd),
                       jnp.dtype(cfg.compute_dtype))
-        kv = jnp.zeros(kv_shape, jnp.bfloat16)      # init_cache's dtype
+        kv = jnp.zeros(pool.shape[1:], pool.dtype)   # one layer of the pool
         clen = jnp.full((self.pool_width,), self.cache_s, jnp.int32)
         dispatch("decode_attention", policy)(q, kv, kv, clen, layout=lay,
                                              policy=policy)
@@ -903,7 +902,7 @@ class KVDecodeState(DecodeState):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.kernels.decode_attention.ops import _sharded_program
         from .transformer import cache_seq_axis as _csa
-        spec = [None] * 4
+        spec = [None] * kv.ndim
         spec[_csa(lay, stacked=False)] = self.kv_axis
         kvs = jax.device_put(kv, NamedSharding(self.mesh, P(*spec)))
         return autotune_policy(
@@ -1102,6 +1101,8 @@ def _paged_scatter_impl(pool, rows, g, sl, page, lay, batch_ax):
         r = r.reshape(L, n, hkv, nc, page, hd).transpose(0, 1, 3, 2, 4, 5)
         r = r.reshape(L, n * nc, hkv, page, hd)
     else:
+        if rows.ndim == 4:               # lane-dense prefill rows
+            rows = rows.reshape(rows.shape[:3] + pool.shape[-2:])
         n, sp, hkv, hd = rows.shape[1:]
         r = jnp.pad(rows, [(0, 0)] * 2 + [(0, nc * page - sp),
                                           (0, 0), (0, 0)])
@@ -1116,15 +1117,16 @@ _paged_scatter_jit = jax.jit(_paged_scatter_impl,
 def _paged_scatter(pool, rows, gids, page, lay, *, rows_sel=None):
     """Scatter per-slot prefill KV into pool pages. ``pool`` is a stacked
     (L, N, page, Hkv, hd) ("bshd") / (L, N, Hkv, page, hd) ("bhsd") pool;
-    ``rows`` the admitted rows of the prefill cache, (L, n, sp, Hkv, hd) /
-    (L, n, Hkv, sp, hd); ``gids`` (n, ceil(sp/page)) GLOBAL page positions
-    (the sharded pool's global axis order is partition-major, matching
-    the allocator's gid layout). A partial last page is zero-padded —
-    those positions sit beyond every reader's ``cache_len`` until decode
-    overwrites them. Jitted (shape-keyed) so an admission pays one
-    dispatch, not one per pad/reshape/scatter op. ``rows_sel=(sl, axis)``
-    folds the admitted-row gather of the full prefill cache into the
-    same program instead of an eager advanced-index on the host path."""
+    ``rows`` the admitted rows of the prefill cache, (L, n, sp, Hkv*hd) or
+    (L, n, sp, Hkv, hd) / (L, n, Hkv, sp, hd); ``gids`` (n, ceil(sp/page))
+    GLOBAL page positions (the sharded pool's global axis order is
+    partition-major, matching the allocator's gid layout). A partial last
+    page is zero-padded — those positions sit beyond every reader's
+    ``cache_len`` until decode overwrites them. Jitted (shape-keyed) so
+    an admission pays one dispatch, not one per pad/reshape/scatter op.
+    ``rows_sel=(sl, axis)`` folds the admitted-row gather of the full
+    prefill cache into the same program instead of an eager
+    advanced-index on the host path."""
     g = jnp.asarray(np.asarray(gids).reshape(-1), jnp.int32)
     if rows_sel is None:
         return _paged_scatter_jit(pool, rows, g, None, page, lay, 0)

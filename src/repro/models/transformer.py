@@ -87,7 +87,7 @@ def attn_apply(x, p, cfg, pos, *, window=None, causal=None, kv_valid=None,
 def cache_seq_axis(layout: str, stacked: bool = True) -> int:
     """Index of the sequence axis in a KV cache of the given layout.
 
-    Stacked caches are (L, B, S, Hkv, hd) for "bshd" and (L, B, Hkv, S, hd)
+    Stacked caches are (L, B, S, Hkv*hd) for "bshd" and (L, B, Hkv, S, hd)
     for "bhsd"; per-layer caches drop the leading L. Resolving the axis
     here (instead of hardcoding -3, which is only correct for "bshd")
     keeps every cache pad/insert site layout-correct.
@@ -117,8 +117,9 @@ def _rope_pos(b, pos):
 def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False):
     """Write one token's K (or V) into the cache at ``pos``.
 
-    kv: (B, 1, Hkv, hd) for "bshd" / (B, Hkv, 1, hd) for "bhsd".
-    ``pos`` scalar writes one slice (dynamic_update_slice); a per-slot
+    kv: (B, 1, Hkv, hd) for "bshd" / (B, Hkv, 1, hd) for "bhsd"; a
+    lane-dense (B, S, Hkv*hd) "bshd" cache takes it as one (B, 1, Hkv*hd)
+    row. ``pos`` scalar writes one slice (dynamic_update_slice); a per-slot
     (B,) vector scatters each row at its own position, so ragged slots in
     a continuous batch never touch each other's cache rows.
 
@@ -132,6 +133,8 @@ def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False):
     genuinely droppable index) first.
     """
     kv = kv.astype(cache.dtype)
+    if cache.ndim == 3:
+        kv = kv.reshape(kv.shape[0], 1, -1)
     if jnp.ndim(pos) == 0:
         assert not oob_drop, "oob_drop needs a per-row position vector"
         ax = 2 if layout == "bhsd" else 1
@@ -152,7 +155,7 @@ def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False):
 
 def attn_decode(x, p, cfg, cache_k, cache_v, pos, *, window=None,
                 policy=None, write_pos=None, oob_drop=False):
-    """Single-token decode. cache_[kv]: (B, Smax, Hkv, hd) for "bshd"
+    """Single-token decode. cache_[kv]: (B, Smax, Hkv*hd) for "bshd"
     layout, (B, Hkv, Smax, hd) for "bhsd"; pos: scalar int or per-slot
     (B,) vector of current positions. Returns y, (new_k_cache,
     new_v_cache).
@@ -406,14 +409,16 @@ def loss_fn(params, cfg, batch, *, policy=None):
 
 
 def init_cache(cfg, batch, seq_len, dtype=jnp.bfloat16):
-    """Stacked KV cache: (L, B, S, Hkv, hd) ("bshd") or (L, B, Hkv, S, hd)
-    ("bhsd") ×2. Windowed archs allocate only the window (ring-buffer
-    semantics handled by position clamping)."""
+    """Stacked KV cache: (L, B, S, Hkv*hd) ("bshd") or (L, B, Hkv, S, hd)
+    ("bhsd") ×2. "bshd" folds the heads into the lanes, the form the
+    flash-decode kernel reads with no copy (``kernels.decode_attention``).
+    Windowed archs allocate only the window (ring-buffer semantics handled
+    by position clamping)."""
     s = min(seq_len, cfg.sliding_window) if cfg.sliding_window else seq_len
     if cfg.kv_cache_layout == "bhsd":
         shape = (cfg.n_layers, batch, cfg.n_kv_heads, s, cfg.hd)
     else:
-        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.hd)
+        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads * cfg.hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -484,6 +489,8 @@ def prefill(params, cfg, tokens, extra=None, *, prompt_len=None, policy=None,
             v = jnp.roll(v[:, -w:], s % w, axis=1)
         if cfg.kv_cache_layout == "bhsd":
             k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        else:                                    # init_cache's lane-dense form
+            k, v = k.reshape(*k.shape[:2], -1), v.reshape(*v.shape[:2], -1)
         return y, {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
 
     if cfg.remat:
@@ -513,10 +520,12 @@ def _write_chunk_kv(cache, kv, rows, layout):
 
     kv: (B, C, Hkv, hd); rows: (B, C) absolute cache positions with
     invalid lanes pre-remapped to S (a droppable index); cache is one
-    layer's slot pool row block — (B, S, Hkv, hd) "bshd" / (B, Hkv, S, hd)
-    "bhsd"."""
+    layer's slot pool row block — (B, S, Hkv*hd) or (B, S, Hkv, hd) "bshd"
+    / (B, Hkv, S, hd) "bhsd"."""
     kv = kv.astype(cache.dtype)
     b, c = rows.shape
+    if cache.ndim == 3:
+        kv = kv.reshape(b, c, -1)
     if layout == "bhsd":
         hkv = cache.shape[1]
         return cache.at[jnp.arange(b)[:, None, None],
@@ -543,8 +552,10 @@ def _attn_chunk(x, p, cfg, ck, cv, off, clens, *, policy=None):
     rows = jnp.where(lane, pos, s)                         # invalid -> drop
     ck = _write_chunk_kv(ck, k, rows, lay)
     cv = _write_chunk_kv(cv, v, rows, lay)
-    kk, vv = ((ck, cv) if lay == "bshd"
-              else (ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3)))
+    if lay == "bshd":
+        kk, vv = (x.reshape(b, s, cfg.n_kv_heads, cfg.hd) for x in (ck, cv))
+    else:
+        kk, vv = ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3)
     # stale rows of a reused slot (and rows beyond this row's progress)
     # are masked out of both weights and normalizer.
     kv_valid = jnp.arange(s)[None, :] < (off + clens)[:, None]

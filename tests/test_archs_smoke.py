@@ -104,8 +104,7 @@ def test_decode_matches_prefill_tail():
     # prefill first s-1 tokens, then decode the last one
     head_logits, cache = api.prefill(params, cfg, {"tokens": toks[:, :-1]})
     # grow cache to length s
-    ck = jnp.zeros((cfg.n_layers, b, s, cfg.n_kv_heads, cfg.hd),
-                   jnp.bfloat16).at[:, :, :s - 1].set(cache["k"])
+    ck = api.init_cache(cfg, b, s)["k"].at[:, :, :s - 1].set(cache["k"])
     cv = jnp.zeros_like(ck).at[:, :, :s - 1].set(cache["v"])
     dec_logits, _ = api.decode_step(params, cfg, toks[:, -1:],
                                     {"k": ck, "v": cv}, jnp.int32(s - 1))

@@ -367,8 +367,10 @@ def test_write_token_kv_oob_drop_negative_positions():
     positions, which a bare mode="drop" scatter would wrap numpy-style —
     must leave the cache untouched."""
     from repro.models.transformer import _write_token_kv
-    for layout in ("bshd", "bhsd"):
-        shape = (2, 5, 3, 8) if layout == "bshd" else (2, 3, 5, 8)
+    # "bshd": the lane-dense pool layer (B, S, Hkv*hd) and the 4-D form
+    # the hybrid family's ring caches keep
+    for layout, shape in (("bshd", (2, 5, 24)), ("bshd", (2, 5, 3, 8)),
+                          ("bhsd", (2, 3, 5, 8))):
         kv_shape = (2, 1, 3, 8) if layout == "bshd" else (2, 3, 1, 8)
         cache = jnp.zeros(shape, jnp.float32)
         kv = jnp.ones(kv_shape, jnp.float32)
@@ -389,7 +391,7 @@ def test_write_token_kv_oob_drop_negative_positions():
 # ------------------------------------------------------- cache layout axis
 
 def test_cache_seq_axis():
-    """"bshd" stacked caches are (L, B, S, Hkv, hd) -> axis 2; "bhsd" are
+    """"bshd" stacked caches are (L, B, S, Hkv*hd) -> axis 2; "bhsd" are
     (L, B, Hkv, S, hd) -> axis 3 (the old _grow_cache hardcoded -3, which
     padded Hkv on head-major caches)."""
     assert cache_seq_axis("bshd") == 2
@@ -404,3 +406,6 @@ def test_cache_seq_axis():
         c = api.init_cache(dataclasses.replace(cfg, kv_cache_layout=lay),
                            2, 32)
         assert c["k"].shape[cache_seq_axis(lay)] == 32
+        if lay == "bshd":         # heads folded into the lanes
+            assert c["k"].shape == (cfg.n_layers, 2, 32,
+                                    cfg.n_kv_heads * cfg.hd)

@@ -50,15 +50,16 @@ def mesh2x4():
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def qkv(b, h, hkv, d, smax, layout, seed=0):
+    # "bshd" caches in the serving pool's lane-dense (B, S, Hkv*d) form
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
-    shape = ((b, hkv, smax, d) if layout == "bhsd" else (b, smax, hkv, d))
+    shape = ((b, hkv, smax, d) if layout == "bhsd" else (b, smax, hkv * d))
     kc = jax.random.normal(ks[1], shape, jnp.float32)
     vc = jax.random.normal(ks[2], shape, jnp.float32)
     return q, kc, vc
 
 def shard_cache(mesh, kc, vc, layout):
-    spec = [None] * 4
+    spec = [None] * kc.ndim
     spec[2 if layout == "bhsd" else 1] = "model"
     s = NamedSharding(mesh, P(*spec))
     return jax.device_put(kc, s), jax.device_put(vc, s)

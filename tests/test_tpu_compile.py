@@ -5,14 +5,17 @@ that break the (8, 128) tiling rule, vector shape casts Mosaic does not
 lower). These tests compile each serving kernel with ``interpret=False``
 at gpt2-small widths (12 heads, head dim 64, 1024 positions, 8 slots) for
 a described ``v5e:2x2`` topology and check that the kernel survived into
-the program as a ``tpu_custom_call``.
+the program as a ``tpu_custom_call``; the serving decode program is
+compiled whole, and its layer loop inspected op by op.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
 this file.
 """
 
+import math
 import os
+import re
 
 import pytest
 import jax
@@ -57,6 +60,22 @@ def _compile(fn, *shapes):
     return compiled
 
 
+def _check_kernel_bitcasts(text):
+    """Where each kernel row holds several heads in its lanes, no bitcast
+    of the Pallas call's output may drop elements: such a bitcast keeps
+    the same lanes of every row (right for a lane-padded head, which
+    keeps its first d). On a v5e, XLA folded the reshape, per-row lane
+    slices and stack that map grouped rows back to heads into one, and
+    every second head came out wrong."""
+    lines = text.splitlines()
+    for kname, _, kdims in _array_ops(
+            [ln.strip() for ln in lines if 'tpu_custom_call"' in ln]):
+        for _, op, d in _array_ops(
+                [ln.strip() for ln in lines if f"(%{kname})" in ln]):
+            assert op != "bitcast" or math.prod(d) == math.prod(kdims), \
+                f"{kname} {kdims} bitcast to {d}"
+
+
 def _arg(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -82,9 +101,10 @@ def test_decode_attention(one_chip, layout, exp):
     q = _arg((B, 1, H, D), jnp.bfloat16, one_chip)
     kv = _arg(cache, jnp.bfloat16, one_chip)
     clen = _arg((B,), jnp.int32, one_chip)
-    _compile(lambda q, k, v, c: decode_attention(q, k, v, c, layout=layout,
-                                                 policy=pol),
-             q, kv, kv, clen)
+    compiled = _compile(lambda q, k, v, c: decode_attention(
+        q, k, v, c, layout=layout, policy=pol), q, kv, kv, clen)
+    if layout == "bshd":                 # two heads of 64 per lane block
+        _check_kernel_bitcasts(compiled.as_text())
 
 
 @pytest.mark.parametrize("layout", ["bshd", "bhsd"])
@@ -114,3 +134,78 @@ def test_decode_attention_partial_packed(one_chip, layout):
     off = _arg((), jnp.int32, one_chip)
     _compile(lambda q, k, v, c, o: decode_attention_partial_packed(
         q, k, v, c, o, layout=layout, policy=pol), q, kv, kv, clen, off)
+
+
+# ------------------------------------------- the serving decode program
+
+SLOTS = 32                            # the benchmark's pool: 32 x 1024
+
+
+def _array_ops(lines):
+    """(name, opcode, dims) of each array-valued instruction."""
+    out = []
+    for ln in lines:
+        m = re.match(r"(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     ln)
+        if m:
+            dims = tuple(int(x) for x in m.group(2).split(",") if x)
+            out.append((m.group(1), m.group(3), dims))
+    return out
+
+
+def _loop_body(text):
+    """Top-level instructions of the program's one while loop (the layer
+    scan): computations are ``%name (...) -> ... {`` blocks of indented
+    instruction lines."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%(\S+) \(", ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and ln.startswith("  "):
+            cur.append(ln.strip())
+    bodies = re.findall(r" while\(.*?body=%([\w.-]+)", text)
+    assert len(bodies) == 1, f"{len(bodies)} while loops"
+    return comps[bodies[0]]
+
+
+def test_serving_decode_step_reads_pool_unpadded(one_chip):
+    """The decode program the server runs, at gpt2-small widths over the
+    benchmark's pool: its layer loop reads each layer's K and V as the
+    pool stores them. No pad, no copy of a layer's cache (the 4-D
+    relayout or the 128-lane padded view), one flash-decode kernel."""
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.models.decode_state import _programs
+    from repro.runtime import resolve_policy
+    cfg = get_config("gpt2-small")
+    pol = resolve_policy(cfg, env={}, kernel_backend="pallas",
+                         exp_backend="vexp", interpret=False)
+
+    def arg(x):
+        return _arg(x.shape, x.dtype, one_chip)
+
+    params = jax.tree.map(arg, jax.eval_shape(
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(arg, jax.eval_shape(
+        lambda: api.init_cache(cfg, SLOTS, S)))
+    hkv, hd = cfg.n_kv_heads, cfg.hd
+    assert cache["k"].shape == (cfg.n_layers, SLOTS, S, hkv * hd)
+    tok = _arg((SLOTS, 1), jnp.int32, one_chip)
+    vec = _arg((SLOTS,), jnp.int32, one_chip)
+    decode = _programs(cfg, pol)[2]
+    text = decode.lower(params, tok, cache, vec, vec).compile().as_text()
+    body = _loop_body(text)
+    ops = _array_ops(body)
+    pads = [(n, d) for n, op, d in ops if op == "pad"]
+    assert not pads, f"pads in the layer loop: {pads}"
+    layer = SLOTS * S * hkv * hd
+    copies = [(n, d) for n, op, d in ops
+              if op == "copy" and math.prod(d) >= layer]
+    assert not copies, f"layer-sized copies in the layer loop: {copies}"
+    assert not [d for _, _, d in ops
+                if d in ((SLOTS, S, hkv, hd), (SLOTS, S, hkv * 128))]
+    kernels = [ln for ln in body if 'custom_call_target="tpu_custom_call"'
+               in ln]
+    assert len(kernels) == 1, f"{len(kernels)} Pallas calls per layer"
+    _check_kernel_bitcasts(text)
