@@ -242,13 +242,16 @@ def attention(q, k, v, *, causal=True, window=None, exp_impl="vexp",
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
                      exp_impl="vexp", sm_scale=None, mm_dtype="f32",
-                     layout="bshd", policy=None):
+                     layout="bshd", policy=None, layer=None):
     """Single-token decode attention over a (possibly sequence-sharded) cache.
 
     q: (B, 1, H, D); caches: (B, S_max, Hkv, D) or, as the serving pool
     stores them, (B, S_max, Hkv*D) ("bshd"), or (B, Hkv, S_max, D)
     ("bhsd"); cache_len: scalar or (B,) number of valid positions (the new
-    token's K/V must already be written).
+    token's K/V must already be written). With ``layer`` (traced int32
+    scalar) the caches are the stacked serving pool, (L, ...) of the
+    above, read at that layer: the fused kernel selects it in its index
+    maps, this reduction slices it out.
 
     Written as pure max/sum reductions over the cache sequence axis so that a
     cache sharded along S lowers to partial (m, l, acc) per shard + a cheap
@@ -266,8 +269,11 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
         if policy.kernel_backend == "pallas" and cl.ndim <= 1:
             from repro.kernels.decode_attention import ops as dec_ops
             return dec_ops.decode_attention_policy(
-                q, k_cache, v_cache, cache_len, window=window,
+                q, k_cache, v_cache, cache_len, layer=layer, window=window,
                 sm_scale=sm_scale, layout=layout, policy=policy)
+    if layer is not None:
+        k_cache, v_cache = (jax.lax.dynamic_index_in_dim(x, layer, 0, False)
+                            for x in (k_cache, v_cache))
     exp_fn = _resolve(exp_impl)
     b, _, h, d = q.shape
     if k_cache.ndim == 3:                  # lane-dense "bshd" pool layer

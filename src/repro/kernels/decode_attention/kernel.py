@@ -11,7 +11,10 @@ Two cache layouts share one kernel body: head-major "bhsd" (B, Hkv, S, d)
 — the §Perf C3 layout — and sequence-major "bshd" (B, S, nH * d) with the
 heads folded into the lanes; the BlockSpec index maps place the KV-sweep
 axis wherever the layout stores it, so neither layout pays a materialized
-transpose.
+transpose. The kernel reads the serving pool as it is stacked, (L, ...)
+with one such cache per layer, at a scalar-prefetched layer index: the
+decode step's layer scan hands it the whole pool and no layer is sliced
+out (a single layer's cache is read as a pool of one).
 
 Grid = (nB, nH, nS) with the KV sweep innermost; each program handles one
 lane block's query rows (GQA: G = H // Hkv rows per KV head; a "bshd"
@@ -64,7 +67,7 @@ DEFAULT_BLOCK_B = 8
 _ACCUM_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
-def _decode_kernel(len_ref, off_ref, q_ref, k_ref, v_ref, *refs,
+def _decode_kernel(layer_ref, len_ref, off_ref, q_ref, k_ref, v_ref, *refs,
                    block_b: int, block_s: int, ns: int, s_valid: int,
                    sm_scale: float, exp_impl: str, window, layout: str,
                    partial: bool, packed: bool = False):
@@ -177,24 +180,6 @@ def resolve_block_b(b: int, block_s: int, d: int) -> int:
     return max(bb, 1)
 
 
-def _specs(layout: str, block_b: int, g: int, bs: int, d: int):
-    """(smem, q, k/v) BlockSpecs for the given layout; grid (nB, nH, nS).
-    "bshd" caches arrive as (B, S, nH * d): one head's or lane block's
-    (bs, d) tile is lane-aligned, where a (bs, 1, d) block of a 4-D array
-    would break the (8, 128) tiling rule on its minor dims."""
-    from jax.experimental.pallas import tpu as pltpu
-    q_spec = pl.BlockSpec((block_b, 1, g, d),
-                          lambda bb, hh, si: (bb, hh, 0, 0))
-    if layout == "bhsd":
-        kv_spec = pl.BlockSpec((block_b, 1, bs, d),
-                               lambda bb, hh, si: (bb, hh, si, 0))
-    else:
-        kv_spec = pl.BlockSpec((block_b, bs, d),
-                               lambda bb, hh, si: (bb, si, hh))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return smem, q_spec, kv_spec
-
-
 def _scratch(block_b: int, g: int, d: int, accum_dtype: str):
     from jax.experimental.pallas import tpu as pltpu
     adt = _ACCUM_DTYPES[accum_dtype]
@@ -203,50 +188,90 @@ def _scratch(block_b: int, g: int, d: int, accum_dtype: str):
             pltpu.VMEM((block_b, g, d), adt)]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "block_s", "s_valid", "interpret", "exp_impl", "window",
-    "layout", "accum_dtype"))
-def decode_attention_kernel(q, k_cache, v_cache, cache_len, seq_offset, *,
-                            sm_scale: float, s_valid: int,
-                            block_s: int = DEFAULT_BLOCK_S,
-                            interpret: bool = False,
-                            exp_impl: str = "vexp",
-                            window=None, layout: str = "bhsd",
-                            accum_dtype: str = "float32"):
-    """q: (B, nH, G, d) query rows per head or lane block; caches:
-    (B, nH, S, d) ("bhsd") or (B, S, nH * d) ("bshd"); cache_len: (B,)
-    int32 per-row valid lengths (broadcast a scalar before calling);
-    seq_offset: (1,) int32 absolute position of this cache slice's first
-    row (zero when unsharded); s_valid: unpadded cache length (padded rows
-    above it are never attended). Returns (B, nH, G, d). S divisible by
-    block_s, B by the row block; d a multiple of 128 — all handled by
-    ops."""
+def _contiguous_call(q, k_pool, v_pool, layer, cache_len, seq_offset, *,
+                     sm_scale, s_valid, block_s, interpret, exp_impl,
+                     window, layout, accum_dtype, partial, packed):
+    """The contiguous sweep over layer ``layer[0]`` of a stacked pool.
+
+    ``layer``, ``cache_len`` and ``seq_offset`` ride in as scalar-prefetch
+    operands (SMEM), so the K/V index maps pick the layer's blocks out of
+    the pool where it lies: no slice of the layer is ever made. Grid
+    (nB, nH, nS), KV sweep innermost. "bshd" pools are (L, B, S, lanes):
+    one head's or lane block's (bs, d) tile is lane-aligned, where a
+    (bs, 1, d) block of a 5-D array would break the (8, 128) tiling rule
+    on its minor dims; "bhsd" pools are (L, B, Hkv, S, d)."""
+    from jax.experimental.pallas import tpu as pltpu
     b, hkv, g, d = q.shape
-    smax = k_cache.shape[2] if layout == "bhsd" else k_cache.shape[1]
+    smax = k_pool.shape[3] if layout == "bhsd" else k_pool.shape[2]
     bs = min(block_s, smax)
     ns = smax // bs
     bb = resolve_block_b(b, bs, d)
     kernel = functools.partial(
         _decode_kernel, block_b=bb, block_s=bs, ns=ns, s_valid=s_valid,
         sm_scale=sm_scale, exp_impl=exp_impl, window=window, layout=layout,
-        partial=False)
-    smem, q_spec, kv_spec = _specs(layout, bb, g, bs, d)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(b // bb, hkv, ns),
-        in_specs=[smem, smem, q_spec, kv_spec, kv_spec],
-        out_specs=pl.BlockSpec((bb, 1, g, d),
-                               lambda bb_, hh, si: (bb_, hh, 0, 0)),
-        scratch_shapes=_scratch(bb, g, d, accum_dtype),
-        interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
+        partial=partial, packed=packed)
+    q_spec = pl.BlockSpec((bb, 1, g, d),
+                          lambda bi, hh, si, lay, ln, off: (bi, hh, 0, 0))
+    if layout == "bhsd":
+        kv_spec = pl.BlockSpec(
+            (None, bb, 1, bs, d),
+            lambda bi, hh, si, lay, ln, off: (lay[0], bi, hh, si, 0))
+    else:
+        kv_spec = pl.BlockSpec(
+            (None, bb, bs, d),
+            lambda bi, hh, si, lay, ln, off: (lay[0], bi, si, hh))
+    out_map = lambda bi, hh, si, lay, ln, off: (bi, hh, 0, 0)  # noqa: E731
+    if packed:
+        out_shape = jax.ShapeDtypeStruct((b, hkv, g, d + 2), jnp.float32)
+        out_specs = pl.BlockSpec((bb, 1, g, d + 2), out_map)
+    elif partial:
+        out_shape = [jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32)]
+        stat = pl.BlockSpec((bb, 1, g, 1), out_map)
+        out_specs = [stat, stat, pl.BlockSpec((bb, 1, g, d), out_map)]
+    else:
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        out_specs = pl.BlockSpec((bb, 1, g, d), out_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(b // bb, hkv, ns),
+        in_specs=[q_spec, kv_spec, kv_spec], out_specs=out_specs,
+        scratch_shapes=_scratch(bb, g, d, accum_dtype))
+    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                          interpret=interpret)(
+        layer, cache_len, seq_offset, q, k_pool, v_pool)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "block_s", "s_valid", "interpret", "exp_impl", "window",
-    "layout", "accum_dtype"))
-def decode_attention_kernel_partial(q, k_cache, v_cache, cache_len,
+_CONTIGUOUS_STATIC = ("sm_scale", "block_s", "s_valid", "interpret",
+                      "exp_impl", "window", "layout", "accum_dtype")
+
+
+@functools.partial(jax.jit, static_argnames=_CONTIGUOUS_STATIC)
+def decode_attention_kernel(q, k_pool, v_pool, layer, cache_len, seq_offset,
+                            *, sm_scale: float, s_valid: int,
+                            block_s: int = DEFAULT_BLOCK_S,
+                            interpret: bool = False,
+                            exp_impl: str = "vexp",
+                            window=None, layout: str = "bhsd",
+                            accum_dtype: str = "float32"):
+    """q: (B, nH, G, d) query rows per head or lane block; pools: stacked
+    (L, B, nH, S, d) ("bhsd") or (L, B, S, nH * d) ("bshd"), read at layer
+    ``layer`` ((1,) int32; a single layer's cache is passed as
+    ``cache[None]`` with layer 0); cache_len: (B,) int32 per-row valid
+    lengths (broadcast a scalar before calling); seq_offset: (1,) int32
+    absolute position of this cache slice's first row (zero when
+    unsharded); s_valid: unpadded cache length (padded rows above it are
+    never attended). Returns (B, nH, G, d). S divisible by block_s, B by
+    the row block; d a multiple of 128 — all handled by ops."""
+    return _contiguous_call(
+        q, k_pool, v_pool, layer, cache_len, seq_offset, sm_scale=sm_scale,
+        s_valid=s_valid, block_s=block_s, interpret=interpret,
+        exp_impl=exp_impl, window=window, layout=layout,
+        accum_dtype=accum_dtype, partial=False, packed=False)
+
+
+@functools.partial(jax.jit, static_argnames=_CONTIGUOUS_STATIC)
+def decode_attention_kernel_partial(q, k_pool, v_pool, layer, cache_len,
                                     seq_offset, *, sm_scale: float,
                                     s_valid: int,
                                     block_s: int = DEFAULT_BLOCK_S,
@@ -260,38 +285,15 @@ def decode_attention_kernel_partial(q, k_cache, v_cache, cache_len,
     against the global ``cache_len``). A shard whose slice lies entirely
     outside [cache_len - window, cache_len) returns the merge identity
     (NEG_INF, 0, 0)."""
-    b, hkv, g, d = q.shape
-    smax = k_cache.shape[2] if layout == "bhsd" else k_cache.shape[1]
-    bs = min(block_s, smax)
-    ns = smax // bs
-    bb = resolve_block_b(b, bs, d)
-    kernel = functools.partial(
-        _decode_kernel, block_b=bb, block_s=bs, ns=ns, s_valid=s_valid,
-        sm_scale=sm_scale, exp_impl=exp_impl, window=window, layout=layout,
-        partial=True)
-    smem, q_spec, kv_spec = _specs(layout, bb, g, bs, d)
-    stat = pl.BlockSpec((bb, 1, g, 1), lambda bb_, hh, si: (bb_, hh, 0, 0))
-    return pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-        ],
-        grid=(b // bb, hkv, ns),
-        in_specs=[smem, smem, q_spec, kv_spec, kv_spec],
-        out_specs=[stat, stat,
-                   pl.BlockSpec((bb, 1, g, d),
-                                lambda bb_, hh, si: (bb_, hh, 0, 0))],
-        scratch_shapes=_scratch(bb, g, d, accum_dtype),
-        interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
+    return _contiguous_call(
+        q, k_pool, v_pool, layer, cache_len, seq_offset, sm_scale=sm_scale,
+        s_valid=s_valid, block_s=block_s, interpret=interpret,
+        exp_impl=exp_impl, window=window, layout=layout,
+        accum_dtype=accum_dtype, partial=True, packed=False)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "block_s", "s_valid", "interpret", "exp_impl", "window",
-    "layout", "accum_dtype"))
-def decode_attention_kernel_packed(q, k_cache, v_cache, cache_len,
+@functools.partial(jax.jit, static_argnames=_CONTIGUOUS_STATIC)
+def decode_attention_kernel_packed(q, k_pool, v_pool, layer, cache_len,
                                    seq_offset, *, sm_scale: float,
                                    s_valid: int,
                                    block_s: int = DEFAULT_BLOCK_S,
@@ -306,36 +308,11 @@ def decode_attention_kernel_packed(q, k_cache, v_cache, cache_len,
     merge (``core.softmax.stats_merge_collective_packed``) all_gathers.
     The two stat lanes ride beyond ``d``; the merge slices them off after
     the fold, so the accumulator's lane padding stays untouched."""
-    b, hkv, g, d = q.shape
-    smax = k_cache.shape[2] if layout == "bhsd" else k_cache.shape[1]
-    bs = min(block_s, smax)
-    ns = smax // bs
-    bb = resolve_block_b(b, bs, d)
-    kernel = functools.partial(
-        _decode_kernel, block_b=bb, block_s=bs, ns=ns, s_valid=s_valid,
-        sm_scale=sm_scale, exp_impl=exp_impl, window=window, layout=layout,
-        partial=True, packed=True)
-    smem, q_spec, kv_spec = _specs(layout, bb, g, bs, d)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d + 2), jnp.float32),
-        grid=(b // bb, hkv, ns),
-        in_specs=[smem, smem, q_spec, kv_spec, kv_spec],
-        out_specs=pl.BlockSpec((bb, 1, g, d + 2),
-                               lambda bb_, hh, si: (bb_, hh, 0, 0)),
-        scratch_shapes=_scratch(bb, g, d, accum_dtype),
-        interpret=interpret,
-    )(cache_len, seq_offset, q, k_cache, v_cache)
-
-
-def decode_attention_bhsd(q, k_cache, v_cache, cache_len, *, sm_scale: float,
-                          block_s: int = DEFAULT_BLOCK_S,
-                          interpret: bool = False, exp_impl: str = "vexp"):
-    """Back-compat alias for the head-major unsharded kernel."""
-    return decode_attention_kernel(
-        q, k_cache, v_cache, cache_len, jnp.zeros((1,), jnp.int32),
-        sm_scale=sm_scale, s_valid=k_cache.shape[2], block_s=block_s,
-        interpret=interpret, exp_impl=exp_impl)
+    return _contiguous_call(
+        q, k_pool, v_pool, layer, cache_len, seq_offset, sm_scale=sm_scale,
+        s_valid=s_valid, block_s=block_s, interpret=interpret,
+        exp_impl=exp_impl, window=window, layout=layout,
+        accum_dtype=accum_dtype, partial=True, packed=True)
 
 
 # -------------------------------------------------------------- paged sweep

@@ -12,8 +12,15 @@ A sequence-major cache is read as the pool stores it, (B, S, Hkv*d) with
 the heads folded into the lanes. Where d divides 128 one 128-lane block
 holds 128 // d heads and their queries arrive block-diagonal
 (``_group_q``), so the kernel reads the cache with no copy; a head dim
-that does not divide 128 (and every "bhsd" cache) is lane-padded per
-head, which copies the cache on each call.
+that does not divide 128 (and a "bhsd" cache whose d is not a multiple of
+128) is lane-padded per head, which copies the cache on each call.
+
+``decode_attention`` also reads one layer of the stacked serving pool,
+(L, ...) as ``init_cache`` allocates it, given the layer's index: the
+kernel's index maps select the layer, so the decode step's layer scan
+never slices it out. Only a cache that must be padded first (lanes or a
+sequence not a multiple of the block) has its layer sliced out, then
+padded.
 
 ``decode_attention_sharded`` is the sequence-parallel entry: a KV cache
 sharded along its sequence axis over a mesh axis is swept shard-locally in
@@ -38,7 +45,7 @@ import jax.numpy as jnp
 
 from repro.runtime.policy import ExecPolicy
 from .kernel import (decode_attention_kernel, decode_attention_kernel_partial,
-                     decode_attention_kernel_packed, decode_attention_bhsd,
+                     decode_attention_kernel_packed,
                      decode_attention_kernel_paged,
                      decode_attention_kernel_paged_partial,
                      decode_attention_kernel_paged_packed)
@@ -47,7 +54,7 @@ __all__ = ["decode_attention", "decode_attention_partial",
            "decode_attention_partial_packed",
            "decode_attention_partial_merged",
            "decode_attention_sharded", "decode_attention_policy",
-           "decode_attention_bhsd", "decode_attention_paged",
+           "decode_attention_paged",
            "decode_attention_paged_partial_merged",
            "decode_attention_paged_policy", "paged_gather"]
 
@@ -100,12 +107,13 @@ def _ungroup(x, hp: int, d: int):
     return x.reshape(b, nb * hp, g, d)
 
 
-def _kv_heads(q, k_cache, layout) -> int:
+def _kv_heads(q, shape, layout) -> int:
+    """KV heads of one layer's cache of the given shape."""
     if layout == "bhsd":
-        return k_cache.shape[1]
-    if k_cache.ndim == 3:                  # (B, S, Hkv*d), heads in lanes
-        return k_cache.shape[2] // q.shape[-1]
-    return k_cache.shape[2]
+        return shape[1]
+    if len(shape) == 3:                    # (B, S, Hkv*d), heads in lanes
+        return shape[2] // q.shape[-1]
+    return shape[2]
 
 
 def _lane_heads(q, k_cache, layout) -> int:
@@ -113,43 +121,56 @@ def _lane_heads(q, k_cache, layout) -> int:
     cache the kernel reads unpadded, else 1 (one lane-padded head)."""
     if layout == "bhsd":
         return 1
-    return _heads_per_block(q.shape[-1], _kv_heads(q, k_cache, layout)) or 1
+    hkv = _kv_heads(q, k_cache.shape, layout)
+    return _heads_per_block(q.shape[-1], hkv) or 1
 
 
-def _prepare(q, k_cache, v_cache, cache_len, block_s, layout):
-    """Queries grouped as the kernel reads them, the cache viewed as it
+def _prepare(q, k_cache, v_cache, cache_len, block_s, layout, layer=None):
+    """Queries grouped as the kernel reads them, the pool viewed as it
     reads it, S block-padded, cache_len broadcast to (B,).
 
-    A "bshd" cache is (B, S, Hkv*d) as the pool stores it, or (B, S, Hkv,
-    d); the kernel reads (B, S, lanes) in 128-lane blocks. Where
-    ``_heads_per_block`` is nonzero it reads the cache as it is and the
-    queries come block-diagonal (``_group_q``); otherwise, and for
-    "bhsd", each head is lane-padded to a multiple of 128 (a copy of the
-    cache). Returns (q, k, v, cache_len, smax, hp); ``_ungroup`` maps
-    the kernel's rows back to heads."""
+    With ``layer`` the caches are the stacked pool, (L, B, S, Hkv*d) or
+    (L, B, S, Hkv, d) for "bshd" and (L, B, Hkv, S, d) for "bhsd", read
+    at that layer; without it they are one layer's cache, read as a pool
+    of one at layer 0. The kernel reads (L, B, S, lanes) in 128-lane
+    blocks. Where ``_heads_per_block`` is nonzero it reads the pool as it
+    is and the queries come block-diagonal (``_group_q``); otherwise, and
+    for "bhsd", each head is lane-padded to a multiple of 128. A pad (of
+    the lanes or of S to the block) copies the layer, so the layer is
+    sliced out before it and read at index 0. Returns (q, k, v, layer,
+    cache_len, smax, hp); ``_ungroup`` maps the kernel's rows back to
+    heads."""
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     b, _, h, d = q.shape
-    s_ax = _seq_axis(layout)
+    s_ax = _seq_axis(layout) + 1
     smax = k_cache.shape[s_ax]
-    hkv = _kv_heads(q, k_cache, layout)
+    hkv = _kv_heads(q, k_cache.shape[1:], layout)
     qg = q.reshape(b, hkv, h // hkv, d)
     hp = _heads_per_block(d, hkv) if layout == "bshd" else 0
+    lane_pad = 0 if hp else -(-d // 128) * 128 - d
     if hp:
         qp = qg if hp == 1 else _group_q(qg, hp)
-
-        def lanes(x):
-            return x.reshape(b, smax, hkv * d)
     else:
-        hp, d_pad = 1, -(-d // 128) * 128
-        qp = jnp.pad(qg, [(0, 0)] * 3 + [(0, d_pad - d)])
+        hp = 1
+        qp = jnp.pad(qg, [(0, 0)] * 3 + [(0, lane_pad)])
 
-        def lanes(x):
-            if layout == "bhsd":
-                return jnp.pad(x, [(0, 0)] * 3 + [(0, d_pad - d)])
-            x = jnp.pad(x.reshape(b, smax, hkv, d),
-                        [(0, 0)] * 3 + [(0, d_pad - d)])
-            return x.reshape(b, smax, hkv * d_pad)
+    def lanes(x):
+        if not lane_pad:
+            return (x if layout == "bhsd"
+                    else x.reshape(x.shape[0], b, smax, hkv * d))
+        if layout == "bhsd":
+            return jnp.pad(x, [(0, 0)] * 4 + [(0, lane_pad)])
+        x = jnp.pad(x.reshape(x.shape[0], b, smax, hkv, d),
+                    [(0, 0)] * 4 + [(0, lane_pad)])
+        return x.reshape(x.shape[0], b, smax, hkv * (d + lane_pad))
     bs = min(block_s, smax)
     s_pad = -(-smax // bs) * bs
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if lane_pad or s_pad != smax:
+        k_cache, v_cache = (jax.lax.dynamic_slice_in_dim(x, layer[0], 1)
+                            for x in (k_cache, v_cache))
+        layer = jnp.zeros((1,), jnp.int32)
 
     def view(x):
         x = lanes(x)
@@ -161,7 +182,7 @@ def _prepare(q, k_cache, v_cache, cache_len, block_s, layout):
 
     clen = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1),
                             (b,))
-    return qp, view(k_cache), view(v_cache), clen, smax, hp
+    return qp, view(k_cache), view(v_cache), layer, clen, smax, hp
 
 
 def _policy_kernel_args(policy: Optional[ExecPolicy], block_s, interpret):
@@ -180,23 +201,24 @@ def _policy_kernel_args(policy: Optional[ExecPolicy], block_s, interpret):
 @functools.partial(jax.jit, static_argnames=("window", "sm_scale", "layout",
                                              "block_s", "interpret",
                                              "policy"))
-def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
-                     sm_scale=None, layout="bhsd", block_s=512,
+def decode_attention(q, k_cache, v_cache, cache_len, *, layer=None,
+                     window=None, sm_scale=None, layout="bhsd", block_s=512,
                      interpret=None, policy: Optional[ExecPolicy] = None):
     """Fused flash-decode. q: (B, 1, H, d); caches: (B, Hkv, S, d) ("bhsd")
-    or (B, S, Hkv*d) / (B, S, Hkv, d) ("bshd"); cache_len: scalar int32
-    or per-row (B,) int32 of valid positions (the serving engine's
-    per-slot lengths); ``window``: static sliding window (attend exactly
-    the last ``window`` positions of each row's valid range). Returns
-    (B, 1, H, d)."""
+    or (B, S, Hkv*d) / (B, S, Hkv, d) ("bshd"), or with ``layer`` (a
+    traced int32 scalar) the stacked pool of such caches, (L, ...), read
+    at that layer; cache_len: scalar int32 or per-row (B,) int32 of valid
+    positions (the serving engine's per-slot lengths); ``window``: static
+    sliding window (attend exactly the last ``window`` positions of each
+    row's valid range). Returns (B, 1, H, d)."""
     exp_impl, accum, block_s, interpret = _policy_kernel_args(
         policy, block_s, interpret)
     b, _, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax, hp = _prepare(q, k_cache, v_cache, cache_len,
-                                          block_s, layout)
+    qp, kp, vp, lay, clen, smax, hp = _prepare(
+        q, k_cache, v_cache, cache_len, block_s, layout, layer)
     out = decode_attention_kernel(
-        qp, kp, vp, clen, jnp.zeros((1,), jnp.int32), sm_scale=scale,
+        qp, kp, vp, lay, clen, jnp.zeros((1,), jnp.int32), sm_scale=scale,
         s_valid=smax, block_s=block_s, interpret=interpret,
         exp_impl=exp_impl, window=window, layout=layout, accum_dtype=accum)
     return _ungroup(out, hp, d).reshape(b, 1, h, d)
@@ -221,11 +243,11 @@ def decode_attention_partial(q, k_cache, v_cache, cache_len, seq_offset, *,
         policy, block_s, interpret)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax, hp = _prepare(q, k_cache, v_cache, cache_len,
-                                          block_s, layout)
+    qp, kp, vp, lay, clen, smax, hp = _prepare(
+        q, k_cache, v_cache, cache_len, block_s, layout)
     off = jnp.asarray(seq_offset, jnp.int32).reshape(1)
     m, l, acc = decode_attention_kernel_partial(
-        qp, kp, vp, clen, off, sm_scale=scale, s_valid=smax,
+        qp, kp, vp, lay, clen, off, sm_scale=scale, s_valid=smax,
         block_s=block_s, interpret=interpret, exp_impl=exp_impl,
         window=window, layout=layout, accum_dtype=accum)
     acc = _ungroup(acc, hp, d)
@@ -255,11 +277,11 @@ def decode_attention_partial_packed(q, k_cache, v_cache, cache_len,
         policy, block_s, interpret)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qp, kp, vp, clen, smax, _ = _prepare(q, k_cache, v_cache, cache_len,
-                                         block_s, layout)
+    qp, kp, vp, lay, clen, smax, _ = _prepare(
+        q, k_cache, v_cache, cache_len, block_s, layout)
     off = jnp.asarray(seq_offset, jnp.int32).reshape(1)
     return decode_attention_kernel_packed(
-        qp, kp, vp, clen, off, sm_scale=scale, s_valid=smax,
+        qp, kp, vp, lay, clen, off, sm_scale=scale, s_valid=smax,
         block_s=block_s, interpret=interpret, exp_impl=exp_impl,
         window=window, layout=layout, accum_dtype=accum)
 
@@ -480,21 +502,26 @@ def decode_attention_paged_policy(q, k_pool, v_pool, block_tab, cache_len, *,
                                   layout=layout, policy=policy)
 
 
-def decode_attention_policy(q, k_cache, v_cache, cache_len, *, window=None,
-                            sm_scale=None, layout="bhsd",
+def decode_attention_policy(q, k_cache, v_cache, cache_len, *, layer=None,
+                            window=None, sm_scale=None, layout="bhsd",
                             policy: ExecPolicy):
     """kernels.dispatch entry: policy-driven blocks + optional autotune.
 
     Covers every serving configuration — both cache layouts, sliding
-    windows, scalar or per-slot cache lengths — through the fused kernel;
-    there is no reference fallback."""
+    windows, scalar or per-slot cache lengths, one layer's cache or the
+    stacked pool at ``layer`` — through the fused kernel; there is no
+    reference fallback. Block sizes are tuned per layer's shape."""
     if policy.autotune:
         from repro.kernels.dispatch import autotune_policy
+        one = (k_cache if layer is None else
+               jax.ShapeDtypeStruct(k_cache.shape[1:], k_cache.dtype))
         policy = autotune_policy(
             "decode_attention", policy,
             lambda p: decode_attention(q, k_cache, v_cache, cache_len,
-                                       window=window, sm_scale=sm_scale,
-                                       layout=layout, policy=p),
-            q, k_cache)
-    return decode_attention(q, k_cache, v_cache, cache_len, window=window,
-                            sm_scale=sm_scale, layout=layout, policy=policy)
+                                       layer=layer, window=window,
+                                       sm_scale=sm_scale, layout=layout,
+                                       policy=p),
+            q, one)
+    return decode_attention(q, k_cache, v_cache, cache_len, layer=layer,
+                            window=window, sm_scale=sm_scale, layout=layout,
+                            policy=policy)

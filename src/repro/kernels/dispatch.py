@@ -174,12 +174,15 @@ def _attention_xla(q, k, v, *, causal=True, window=None, sm_scale=None,
 
 
 @hot_path
-def _decode_fallback(q, k_cache, v_cache, cache_len, *, window=None,
-                     sm_scale=None, layout="bshd", policy: ExecPolicy):
+def _decode_fallback(q, k_cache, v_cache, cache_len, *, layer=None,
+                     window=None, sm_scale=None, layout="bshd",
+                     policy: ExecPolicy):
+    """reference/xla decode: the core reduction, which slices layer
+    ``layer`` out of a stacked pool."""
     from repro.core.attention import decode_attention
     return decode_attention(q, k_cache, v_cache, cache_len, window=window,
                             sm_scale=sm_scale, exp_impl=policy.exp_backend,
-                            layout=layout)
+                            layout=layout, layer=layer)
 
 
 @hot_path

@@ -114,14 +114,16 @@ def _rope_pos(b, pos):
     return jnp.full((b, 1), pos, jnp.int32)
 
 
-def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False):
+def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False, layer=None):
     """Write one token's K (or V) into the cache at ``pos``.
 
     kv: (B, 1, Hkv, hd) for "bshd" / (B, Hkv, 1, hd) for "bhsd"; a
     lane-dense (B, S, Hkv*hd) "bshd" cache takes it as one (B, 1, Hkv*hd)
     row. ``pos`` scalar writes one slice (dynamic_update_slice); a per-slot
     (B,) vector scatters each row at its own position, so ragged slots in
-    a continuous batch never touch each other's cache rows.
+    a continuous batch never touch each other's cache rows. With ``layer``
+    (traced int32 scalar) ``cache`` is the stacked pool and the rows land
+    in that layer, in place: nothing of the pool is sliced or copied.
 
     ``oob_drop`` makes out-of-range rows drop instead of clamp — the
     sequence-sharded decode path hands every shard the same write with
@@ -132,32 +134,39 @@ def _write_token_kv(cache, kv, pos, layout, *, oob_drop=False):
     land spurious rows — remap every out-of-slice position to S (a
     genuinely droppable index) first.
     """
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    one = cache.shape[len(lead):]            # one layer's cache
     kv = kv.astype(cache.dtype)
-    if cache.ndim == 3:
+    if len(one) == 3:
         kv = kv.reshape(kv.shape[0], 1, -1)
+    ax = len(lead) + (2 if layout == "bhsd" else 1)
     if jnp.ndim(pos) == 0:
         assert not oob_drop, "oob_drop needs a per-row position vector"
-        ax = 2 if layout == "bhsd" else 1
-        return jax.lax.dynamic_update_slice_in_dim(cache, kv, pos, axis=ax)
+        start = [jnp.int32(0)] * cache.ndim
+        start[ax] = jnp.asarray(pos, jnp.int32)
+        start[:len(lead)] = lead
+        return jax.lax.dynamic_update_slice(
+            cache, kv.reshape((1,) * len(lead) + kv.shape), start)
     kw = {}
     if oob_drop:
-        s = cache.shape[2 if layout == "bhsd" else 1]
+        s = cache.shape[ax]
         pos = jnp.where((pos >= 0) & (pos < s), pos, s)
         kw = {"mode": "drop"}
-    b = cache.shape[0]
+    b = one[0]
     if layout == "bhsd":
-        hkv = cache.shape[1]
-        return cache.at[jnp.arange(b)[:, None],
-                        jnp.arange(hkv)[None, :],
-                        pos[:, None]].set(kv[:, :, 0], **kw)
-    return cache.at[jnp.arange(b), pos].set(kv[:, 0], **kw)
+        hkv = one[1]
+        return cache.at[lead + (jnp.arange(b)[:, None],
+                                jnp.arange(hkv)[None, :],
+                                pos[:, None])].set(kv[:, :, 0], **kw)
+    return cache.at[lead + (jnp.arange(b), pos)].set(kv[:, 0], **kw)
 
 
 def attn_decode(x, p, cfg, cache_k, cache_v, pos, *, window=None,
-                policy=None, write_pos=None, oob_drop=False):
+                policy=None, write_pos=None, oob_drop=False, layer=None):
     """Single-token decode. cache_[kv]: (B, Smax, Hkv*hd) for "bshd"
-    layout, (B, Hkv, Smax, hd) for "bhsd"; pos: scalar int or per-slot
-    (B,) vector of current positions. Returns y, (new_k_cache,
+    layout, (B, Hkv, Smax, hd) for "bhsd", or with ``layer`` the stacked
+    pool of them, written and read at that layer; pos: scalar int or
+    per-slot (B,) vector of current positions. Returns y, (new_k_cache,
     new_v_cache).
 
     ``write_pos`` (with ``oob_drop``) splits the write coordinate from the
@@ -172,13 +181,15 @@ def attn_decode(x, p, cfg, cache_k, cache_v, pos, *, window=None,
         v = v.transpose(0, 2, 1, 3)
     wp = pos if write_pos is None else write_pos
     with jax.named_scope("kv_write"):
-        ck = _write_token_kv(cache_k, k, wp, lay, oob_drop=oob_drop)
-        cv = _write_token_kv(cache_v, v, wp, lay, oob_drop=oob_drop)
+        ck = _write_token_kv(cache_k, k, wp, lay, oob_drop=oob_drop,
+                             layer=layer)
+        cv = _write_token_kv(cache_v, v, wp, lay, oob_drop=oob_drop,
+                             layer=layer)
     with jax.named_scope("attn"):
         o = decode_attention(q, ck, cv, cache_len=pos + 1, window=window,
                              exp_impl=cfg.exp_impl,
                              mm_dtype=cfg.attn_mm_dtype, layout=lay,
-                             policy=policy)
+                             policy=policy, layer=layer)
     return o.reshape(b, 1, -1) @ p["wo"], (ck, cv)
 
 
@@ -643,7 +654,13 @@ def decode_step(params, cfg, token, cache, pos, *, policy=None, live=None):
     ``live`` (B,) int32, serving only: rows with ``live == 0`` (free slots
     and slots mid-chunk-prefill) must not mutate their cache rows — their
     write position is parked at a droppable sentinel. Their (garbage)
-    logits are discarded by the engine as before."""
+    logits are discarded by the engine as before.
+
+    The layer scan carries the stacked pool whole: each layer writes its
+    token rows into the pool in place and flash-decode reads the layer
+    where it lies, by index. A scan over the pool's layers as ``xs`` would
+    slice each layer out, write it back into a second stacked buffer and
+    copy that into the donated output on every step."""
     x = embed_inputs(params, cfg, token)
     dt = _cdtype(cfg)
     # Windowed caches are sized `window`; write position wraps.
@@ -658,8 +675,9 @@ def decode_step(params, cfg, token, cache, pos, *, policy=None, live=None):
                              jnp.asarray(wpos, jnp.int32).reshape(-1), (b,)),
                          PARKED_POS)
 
-    def body(x, inp):
-        layer_p, ck, cv = inp
+    def body(carry, inp):
+        x, ck, cv = carry
+        layer_p, l = inp
         layer_p = jax.tree.map(lambda a: a.astype(dt)
                                if a.dtype == jnp.float32 and a.ndim > 1
                                else a, layer_p)
@@ -670,30 +688,31 @@ def decode_step(params, cfg, token, cache, pos, *, policy=None, live=None):
                 k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             with jax.named_scope("kv_write"):
                 ck = _write_token_kv(ck, k, wpos, cfg.kv_cache_layout,
-                                     oob_drop=drop)
+                                     oob_drop=drop, layer=l)
                 cv = _write_token_kv(cv, v, wpos, cfg.kv_cache_layout,
-                                     oob_drop=drop)
+                                     oob_drop=drop, layer=l)
             h = norm_apply(x, layer_p["ln_attn"], cfg.norm, cfg.norm_eps)
             with jax.named_scope("attn"):
                 y, _ = _decode_windowed(h, layer_p, cfg, ck, cv, pos, wpos,
-                                        policy=policy)
+                                        policy=policy, layer=l)
             with jax.named_scope("mlp"):
                 x = _finish_block(x, h, y, layer_p, cfg, policy=policy)
-            return x, {"k": ck, "v": cv}
+            return (x, ck, cv), None
         h = norm_apply(x, layer_p["ln_attn"], cfg.norm, cfg.norm_eps)
         # attn_decode scopes its cache write ("kv_write") and its
         # attention ("attn")
         a, (ck, cv) = attn_decode(h, layer_p["attn"], cfg, ck, cv, pos,
                                   policy=policy, write_pos=wpos,
-                                  oob_drop=drop)
+                                  oob_drop=drop, layer=l)
         with jax.named_scope("mlp"):
             x = _finish_block(x, h, a, layer_p, cfg, policy=policy)
-        return x, {"k": ck, "v": cv}
+        return (x, ck, cv), None
 
-    x, cache = jax.lax.scan(body, x, (params["layers"],
-                                      cache["k"], cache["v"]),
-                            unroll=cfg.n_layers if cfg.unroll_scans else 1)
-    return _final_logits(params, cfg, x), cache
+    (x, k, v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        unroll=cfg.n_layers if cfg.unroll_scans else 1)
+    return _final_logits(params, cfg, x), {"k": k, "v": v}
 
 
 def _final_logits(params, cfg, x):
@@ -761,15 +780,18 @@ def _qkv_single(x, layer_p, cfg, pos):
     return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), q
 
 
-def _decode_windowed(h, layer_p, cfg, ck, cv, pos, wpos, *, policy=None):
-    """Windowed ring-buffer decode: all cache slots valid once pos >= W."""
+def _decode_windowed(h, layer_p, cfg, ck, cv, pos, wpos, *, policy=None,
+                     layer=None):
+    """Windowed ring-buffer decode: all cache slots valid once pos >= W.
+    With ``layer`` the caches are the stacked pool, read at that layer."""
     b = h.shape[0]
     q, _, _ = _qkv(h, layer_p["attn"], cfg, _rope_pos(b, pos))
     w = cfg.sliding_window
     valid = jnp.minimum(pos + 1, w)
     o = decode_attention(q, ck, cv, cache_len=valid, exp_impl=cfg.exp_impl,
                          mm_dtype=cfg.attn_mm_dtype,
-                         layout=cfg.kv_cache_layout, policy=policy)
+                         layout=cfg.kv_cache_layout, policy=policy,
+                         layer=layer)
     return o.reshape(b, 1, -1) @ layer_p["attn"]["wo"], None
 
 

@@ -2,6 +2,8 @@
 both cache layouts, sliding windows, partial-statistics mode (+ the
 stats_merge algebra), and policy-selected accumulation dtypes."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -281,3 +283,85 @@ def test_lane_dense_partial_stats_merge_matches_full():
     out = (acc * (1.0 / jnp.maximum(stats.l, 1e-30))).reshape(b, 1, h, d)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                atol=2e-3, rtol=2e-3)
+
+
+# ------------------------------------- the stacked pool, read by layer index
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("layout,d,smax", [
+    ("bshd", 64, 512),     # two heads per lane block, read where it lies
+    ("bshd", 128, 512),    # one head per lane block, read where it lies
+    ("bhsd", 128, 512),    # head-major, lanes already whole
+    ("bhsd", 64, 512),     # head-major, lane-padded: the layer is sliced
+    ("bshd", 64, 384),     # S not a multiple of the block: sliced
+])
+def test_stacked_pool_layer_index_equals_layer_slice(layout, d, smax,
+                                                     window):
+    """The kernel over a stacked (L, ...) pool at layer ``l`` equals the
+    same kernel on ``pool[l]`` for every ``l``, bit for bit: the index map
+    selects the same blocks that a slice would hand it."""
+    n_layers, b, h, hkv = 3, 4, 4, 4
+    ks = jax.random.split(jax.random.PRNGKey(21), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
+    one = (b, smax, hkv * d) if layout == "bshd" else (b, hkv, smax, d)
+    kp = jax.random.normal(ks[1], (n_layers,) + one).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (n_layers,) + one).astype(jnp.bfloat16)
+    clens = jnp.array([1, 256, 300, smax], jnp.int32)
+    kw = dict(window=window, layout=layout, block_s=256, interpret=True)
+    for l in range(n_layers):
+        stacked = decode_attention(q, kp, vp, clens, layer=jnp.int32(l),
+                                   **kw)
+        sliced = decode_attention(q, kp[l], vp[l], clens, **kw)
+        np.testing.assert_array_equal(np.asarray(stacked),
+                                      np.asarray(sliced))
+    ref = decode_attention_ref(q, kp[1].astype(jnp.float32),
+                               vp[1].astype(jnp.float32), clens,
+                               window=window, layout=layout)
+    out = decode_attention(q, kp, vp, clens, layer=jnp.int32(1), **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("arch,layout", [("gpt2-small", "bshd"),
+                                         ("gpt2-small", "bhsd"),
+                                         ("h2o-danube3-4b", "bshd")])
+def test_decode_step_parked_rows_leave_pool_untouched(arch, layout):
+    """A ``decode_step`` over the carried pool writes each live row's
+    token at its position in every layer and nothing else: rows with
+    ``live == 0`` keep every pool row bit-untouched (the window ring's
+    branch included)."""
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.runtime import resolve_policy
+    cfg = dataclasses.replace(get_config(arch).reduced(),
+                              kv_cache_layout=layout)
+    pol = resolve_policy(cfg, env={}, kernel_backend="pallas",
+                         exp_backend="vexp")
+    b, s = 4, 32
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    cache = api.init_cache(cfg, b, s)
+    ks = jax.random.split(jax.random.PRNGKey(1), 2)
+    cache = {n: jax.random.normal(k, c.shape).astype(c.dtype)
+             for (n, c), k in zip(sorted(cache.items()), ks)}
+    pos = jnp.array([3, 5, 20, 9], jnp.int32)
+    live = jnp.array([1, 0, 1, 0], jnp.int32)
+    tok = jnp.array([[7], [8], [9], [10]], jnp.int32)
+    _, out = jax.jit(lambda p, c: api.decode_step(
+        p, cfg, tok, c, pos, policy=pol, live=live))(params, cache)
+    s_ax = 2 if cfg.kv_cache_layout == "bshd" else 3
+    wpos = pos % cfg.sliding_window if cfg.sliding_window else pos
+    for name in ("k", "v"):
+        before = np.asarray(jnp.moveaxis(cache[name], s_ax, 2)
+                            .astype(jnp.float32))
+        after = np.asarray(jnp.moveaxis(out[name], s_ax, 2)
+                           .astype(jnp.float32))
+        for row in range(b):
+            if live[row]:
+                written = np.zeros(after.shape[2], bool)
+                written[int(wpos[row])] = True
+                np.testing.assert_array_equal(after[:, row, ~written],
+                                              before[:, row, ~written])
+                assert (after[:, row, written]
+                        != before[:, row, written]).any()
+            else:
+                np.testing.assert_array_equal(after[:, row], before[:, row])

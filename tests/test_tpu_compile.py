@@ -107,6 +107,30 @@ def test_decode_attention(one_chip, layout, exp):
         _check_kernel_bitcasts(compiled.as_text())
 
 
+@pytest.mark.parametrize("layout,d", [("bshd", 64), ("bhsd", 128)])
+def test_decode_attention_stacked_pool(one_chip, layout, d):
+    """Flash-decode over a stacked 12-layer pool at a traced layer index:
+    the kernel takes the whole pool and selects the layer in its index
+    maps, so no layer of it is sliced out before the call."""
+    pol = _policy("vexp")
+    one = (B, S, H * d) if layout == "bshd" else (B, H, S, d)
+    pool = (12,) + one
+    q = _arg((B, 1, H, d), jnp.bfloat16, one_chip)
+    kv = _arg(pool, jnp.bfloat16, one_chip)
+    clen = _arg((B,), jnp.int32, one_chip)
+    lay = _arg((), jnp.int32, one_chip)
+    text = _compile(lambda q, k, v, c, l: decode_attention(
+        q, k, v, c, layer=l, layout=layout, policy=pol),
+        q, kv, kv, clen, lay).as_text()
+    kernel = [ln for ln in text.splitlines() if 'tpu_custom_call"' in ln]
+    assert len(kernel) == 1
+    pool_t = "bf16[" + ",".join(map(str, pool)) + "]"
+    assert kernel[0].count(pool_t) == 2, "the kernel does not read the pool"
+    assert not [d_ for _, op, d_ in _array_ops(
+        [ln.strip() for ln in text.splitlines()])
+        if op in ("dynamic-slice", "copy") and d_[-len(one):] == one]
+
+
 @pytest.mark.parametrize("layout", ["bshd", "bhsd"])
 def test_decode_attention_paged(one_chip, layout):
     pol = _policy("vexp")
@@ -169,11 +193,11 @@ def _loop_body(text):
     return comps[bodies[0]]
 
 
-def test_serving_decode_step_reads_pool_unpadded(one_chip):
+@pytest.fixture(scope="module")
+def serving_decode(one_chip):
     """The decode program the server runs, at gpt2-small widths over the
-    benchmark's pool: its layer loop reads each layer's K and V as the
-    pool stores them. No pad, no copy of a layer's cache (the 4-D
-    relayout or the 128-lane padded view), one flash-decode kernel."""
+    benchmark's pool, compiled once for the described chip: (compiled,
+    cfg, number of parameter leaves)."""
     from repro.configs import get_config
     from repro.models import api
     from repro.models.decode_state import _programs
@@ -189,12 +213,22 @@ def test_serving_decode_step_reads_pool_unpadded(one_chip):
         lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
     cache = jax.tree.map(arg, jax.eval_shape(
         lambda: api.init_cache(cfg, SLOTS, S)))
-    hkv, hd = cfg.n_kv_heads, cfg.hd
-    assert cache["k"].shape == (cfg.n_layers, SLOTS, S, hkv * hd)
+    assert cache["k"].shape == (cfg.n_layers, SLOTS, S,
+                                cfg.n_kv_heads * cfg.hd)
     tok = _arg((SLOTS, 1), jnp.int32, one_chip)
     vec = _arg((SLOTS,), jnp.int32, one_chip)
     decode = _programs(cfg, pol)[2]
-    text = decode.lower(params, tok, cache, vec, vec).compile().as_text()
+    compiled = decode.lower(params, tok, cache, vec, vec).compile()
+    return compiled, cfg, len(jax.tree.leaves(params))
+
+
+def test_serving_decode_step_reads_pool_unpadded(serving_decode):
+    """The serving decode program's layer loop reads each layer's K and V
+    as the pool stores them. No pad, no copy of a layer's cache (the 4-D
+    relayout or the 128-lane padded view), one flash-decode kernel."""
+    compiled, cfg, _ = serving_decode
+    hkv, hd = cfg.n_kv_heads, cfg.hd
+    text = compiled.as_text()
     body = _loop_body(text)
     ops = _array_ops(body)
     pads = [(n, d) for n, op, d in ops if op == "pad"]
@@ -209,3 +243,46 @@ def test_serving_decode_step_reads_pool_unpadded(one_chip):
                in ln]
     assert len(kernels) == 1, f"{len(kernels)} Pallas calls per layer"
     _check_kernel_bitcasts(text)
+
+
+def _instructions(text):
+    """Every instruction line of every computation, entry included."""
+    return [ln.strip() for ln in text.splitlines()
+            if ln.startswith("  ") and " = " in ln]
+
+
+def test_serving_decode_step_carries_pool_in_place(serving_decode):
+    """The whole decode program, entry included, moves nothing of the
+    stacked pool but the token rows it writes. The layer scan carries the
+    pool and flash-decode reads its layer by index, so there is no copy,
+    slice or write-back of a layer's or the pool's K/V, no pool-sized
+    buffer the program allocates, and the donated pool aliases the
+    output. The one pool-sized instruction per leaf is the row write, a
+    scatter that updates its operand in place."""
+    compiled, cfg, n_params = serving_decode
+    text = compiled.as_text()
+    layer = SLOTS * S * cfg.n_kv_heads * cfg.hd
+    lines = _instructions(text)
+    big = [(n, op, d, ln) for (n, op, d), ln in      # the K/V arrays
+           ((o, ln) for ln in lines for o in _array_ops([ln]))
+           if math.prod(d) >= layer and SLOTS in d and S in d]
+    moves = [(n, op, d) for n, op, d, _ in big
+             if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+             or op == "fusion" and re.search(
+                 r"copy|dynamic-slice|dynamic-update-slice", n)]
+    assert not moves, f"layer- or pool-sized moves of the pool: {moves}"
+    allocs = [(n, d) for n, op, d, _ in big if op == "custom-call"]
+    assert not allocs, f"pool-sized buffers allocated: {allocs}"
+    writes = [ln for n, op, _, ln in big if op == "fusion"]
+    assert len(writes) == 2, f"{len(writes)} pool-sized fusions"
+    for ln in writes:
+        assert "kv_write/scatter" in ln and '"aliasing_operands"' in ln, ln
+    head = text[:text.index("\n")]
+    alias = dict((int(p), int(o)) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head))
+    pool_args = (n_params + 1, n_params + 2)      # after params and tokens
+    assert all(a in alias for a in pool_args), \
+        f"pool parameters {pool_args} not aliased: {alias}"
+    pool_bytes = cfg.n_layers * layer * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, f"{temp} bytes of temporaries"
